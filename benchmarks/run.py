@@ -74,6 +74,22 @@ def _save(name: str, payload: dict):
 # ---------------------------------------------------------------------------
 
 
+def _run_cpu_child(script: str, timeout: int):
+    """Run a bench child script (``python -c``) pinned to the CPU.
+
+    These benches emulate an 8-device mesh on host CPU devices, and the
+    parent may already hold the accelerator, which a second process
+    cannot open: ``JAX_PLATFORMS=cpu`` keeps the child off it."""
+    import subprocess
+
+    out = subprocess.run(
+        [sys.executable, "-c", script], capture_output=True, text=True,
+        timeout=timeout, env=dict(os.environ, JAX_PLATFORMS="cpu"),
+    )
+    assert out.returncode == 0, out.stderr[-4000:]
+    return out
+
+
 def fig2_convergence(full: bool = False):
     from benchmarks.logreg_runners import (
         reference_optimum,
@@ -544,7 +560,6 @@ def hierarchy(full: bool = False):
     conservation invariant mean_w(u) == update + mean_w(new_memory).
     Writes BENCH_hierarchy.json at the repo root."""
     import dataclasses
-    import subprocess
     import textwrap
 
     from repro.core import buckets as bk
@@ -634,11 +649,7 @@ def hierarchy(full: bool = False):
         """
     ).format(src=os.path.join(_ROOT, "src"), steps=steps)
     t0 = time.time()
-    out = subprocess.run(
-        [sys.executable, "-c", script], capture_output=True, text=True,
-        timeout=1800,
-    )
-    assert out.returncode == 0, out.stderr[-4000:]
+    out = _run_cpu_child(script, timeout=1800)
     rec = json.loads(out.stdout.strip().splitlines()[-1])
     wall_us = (time.time() - t0) * 1e6
 
@@ -705,7 +716,6 @@ def refresh(full: bool = False):
     dynamic==static / conservation / accounting probe
     (repro.core.selfcheck.dynamic_k_selfcheck). Writes
     BENCH_refresh.json at the repo root."""
-    import subprocess
     import textwrap
 
     import jax
@@ -866,11 +876,7 @@ def refresh(full: bool = False):
         """
     ).format(src=os.path.join(_ROOT, "src"), steps=steps)
     t0 = time.time()
-    out = subprocess.run(
-        [sys.executable, "-c", script], capture_output=True, text=True,
-        timeout=1800,
-    )
-    assert out.returncode == 0, out.stderr[-4000:]
+    out = _run_cpu_child(script, timeout=1800)
     rec = json.loads(out.stdout.strip().splitlines()[-1])
     wall_us = (time.time() - t0) * 1e6
     smoke = {
@@ -932,7 +938,6 @@ def budget(full: bool = False):
     + exact accounting) plus a short budget-driven train run — every
     refresh's allocation stays within ``SyncConfig.byte_budget`` with
     zero steady-state recompiles. Writes BENCH_budget.json."""
-    import subprocess
     import textwrap
 
     import jax
@@ -1134,11 +1139,7 @@ def budget(full: bool = False):
         """
     ).format(src=os.path.join(_ROOT, "src"), steps=steps)
     t0 = time.time()
-    out = subprocess.run(
-        [sys.executable, "-c", script], capture_output=True, text=True,
-        timeout=3600,
-    )
-    assert out.returncode == 0, out.stderr[-4000:]
+    out = _run_cpu_child(script, timeout=3600)
     rec = json.loads(out.stdout.strip().splitlines()[-1])
     wall_us = (time.time() - t0) * 1e6
     smoke = {
@@ -1234,7 +1235,6 @@ def overlap(full: bool = False):
     paths — flat, hierarchical, pod-dynamic (with a live mid-run pod-k
     refresh) — plus the synthetic ``overlap_selfcheck`` probe. Writes
     BENCH_overlap.json at the repo root."""
-    import subprocess
     import textwrap
 
     import jax
@@ -1381,11 +1381,7 @@ def overlap(full: bool = False):
     ).format(src=os.path.join(_ROOT, "src"), steps=steps)
     t0 = time.time()
     # six full-model jit compiles on a 1-core container: generous budget
-    out = subprocess.run(
-        [sys.executable, "-c", script], capture_output=True, text=True,
-        timeout=3600,
-    )
-    assert out.returncode == 0, out.stderr[-4000:]
+    out = _run_cpu_child(script, timeout=3600)
     rec = json.loads(out.stdout.strip().splitlines()[-1])
     wall_us = (time.time() - t0) * 1e6
 
@@ -1443,7 +1439,6 @@ def local(full: bool = False):
     quant=15): every run must improve on the init loss with zero
     steady-state recompiles while the accounted bytes/step drop 1/H.
     Writes BENCH_local.json."""
-    import subprocess
     import textwrap
 
     import jax
@@ -1546,11 +1541,7 @@ def local(full: bool = False):
         """
     ).format(src=os.path.join(_ROOT, "src"), steps=steps)
     t0 = time.time()
-    out = subprocess.run(
-        [sys.executable, "-c", script], capture_output=True, text=True,
-        timeout=3600,
-    )
-    assert out.returncode == 0, out.stderr[-4000:]
+    out = _run_cpu_child(script, timeout=3600)
     rec = json.loads(out.stdout.strip().splitlines()[-1])
     wall_us = (time.time() - t0) * 1e6
     runs = rec["runs"]
@@ -1620,7 +1611,6 @@ def matrix(full: bool = False, archs=None):
     all-reduce baseline (compression win). PR CI runs ``--archs
     rwkv6-3b`` only; the weekly schedule sweeps the full zoo.
     """
-    import subprocess
     import textwrap
 
     arch_list = list(archs) if archs else list(MATRIX_ARCHS)
@@ -1694,11 +1684,7 @@ def matrix(full: bool = False, archs=None):
     ).format(src=os.path.join(_ROOT, "src"), steps=steps,
              archs=arch_list, presets=list(MATRIX_PRESETS))
     t0 = time.time()
-    out = subprocess.run(
-        [sys.executable, "-c", script], capture_output=True, text=True,
-        timeout=7200,
-    )
-    assert out.returncode == 0, out.stderr[-4000:]
+    out = _run_cpu_child(script, timeout=7200)
     scenarios = json.loads(out.stdout.strip().splitlines()[-1])["scenarios"]
     wall_us = (time.time() - t0) * 1e6
     n_ok = sum(1 for s in scenarios.values()

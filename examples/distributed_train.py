@@ -57,9 +57,6 @@ from repro.models import build_model  # noqa: E402
 
 
 def main():
-    # NB: on jax < 0.5 the legacy shard_map partial-auto mode cannot
-    # partition a sharded model axis (XLA IsManualSubgroup crash) — use
-    # --model 1 there (see tests/test_distributed.py::legacy_partial_auto).
     n_data = args.data or max(1, args.devices // 2)
     n_model = args.model or (args.devices // n_data)
     mesh = make_mesh((n_data, n_model), ("data", "model"))

@@ -2,7 +2,9 @@
 
 ``get_config(arch_id)`` returns the full production ``ModelConfig``;
 ``get_smoke_config(arch_id)`` the reduced same-family variant (<=2 layers,
-d_model <= 512, <= 4 experts) used by the CPU smoke tests.
+d_model <= 512, <= 4 experts) used by the CPU smoke tests;
+``sized_config(arch_id, size, layers)`` picks one of the two and cuts
+its depth (the train CLI's ``--size`` / ``--layers``).
 
 Assigned architectures (public pool, source in each module):
   rwkv6-3b, qwen1.5-4b, yi-9b, musicgen-medium, qwen3-moe-30b-a3b,
@@ -13,6 +15,7 @@ plus the paper's own workload: logreg (logistic regression, Section 4).
 from __future__ import annotations
 
 import importlib
+from typing import Optional
 
 from repro.configs.base import (
     MESHES,
@@ -54,6 +57,24 @@ def get_smoke_config(arch_id: str) -> ModelConfig:
     return _module(arch_id).smoke_config()
 
 
+def sized_config(arch_id: str, size: str = "smoke",
+                 layers: Optional[int] = None) -> ModelConfig:
+    """``size="full"``: the published config; ``"smoke"``: the reduced
+    variant. ``layers`` cuts depth only — widths, heads, head_dim, LoRA
+    ranks and vocabulary are never cut."""
+    if size == "full":
+        cfg = get_config(arch_id)
+    elif size == "smoke":
+        cfg = get_smoke_config(arch_id)
+    else:
+        raise ValueError(f"unknown size {size!r}; options: smoke, full")
+    if layers is not None:
+        if layers < 1:
+            raise ValueError(f"layers must be >= 1, got {layers}")
+        cfg = cfg.replace(n_layers=layers)
+    return cfg
+
+
 __all__ = [
     "ModelConfig",
     "MeshConfig",
@@ -64,4 +85,5 @@ __all__ = [
     "ARCH_IDS",
     "get_config",
     "get_smoke_config",
+    "sized_config",
 ]

@@ -57,7 +57,6 @@ import jax.numpy as jnp
 
 from repro.core import pipeline
 from repro.core.pipeline import COMM, COMPUTE, QUANT, REPACK
-from repro.utils import compat
 
 Array = jax.Array
 
@@ -595,7 +594,7 @@ def validate_pod_ratios(cfg: SyncConfig, plan) -> None:
 def _axis_size(axis_names: Sequence[str]) -> int:
     n = 1
     for a in axis_names:
-        n = n * compat.axis_size(a)
+        n = n * jax.lax.axis_size(a)
     return n
 
 
@@ -646,10 +645,9 @@ def _row_topk_threshold(u: Array, k: int, constrain=lambda x: x
     GSPMD keeps the batch sharding). Output contract identical to
     ``_row_topk_argmax`` / the Pallas kernels: decreasing |.|, ties to
     the lowest index."""
-    from repro.kernels.topk_select import _threshold_select
+    from repro.kernels.topk_select import threshold_topk_jnp
 
-    iota = jax.lax.broadcasted_iota(jnp.int32, u.shape, u.ndim - 1)
-    vals, idx = _threshold_select(u, iota, None, k)
+    vals, idx = threshold_topk_jnp(u, k)
     return constrain(vals), constrain(idx.astype(jnp.int32))
 
 
@@ -967,7 +965,7 @@ def _hier_stages(shape, dtype, k_row, k_pod, data_axes, pod_axis,
         rows *= s
     cols = shape[-1]
     n_data = _axis_size(data_axes)
-    n_pods = compat.axis_size(pod_axis)
+    n_pods = jax.lax.axis_size(pod_axis)
     name = jnp.dtype(value_dtype).name
     if wire == "packed":
         w1 = enc.WireSpec(rows=rows, cols=cols, k=k_row, value_dtype=name,

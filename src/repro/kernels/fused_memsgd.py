@@ -11,15 +11,16 @@ memory roofline term of the sync stage.
 Per grid step (one (ROW_BLOCK, C) tile resident in VMEM):
     u     = m + eta * g           # elementwise, VPU
     v,i   = row_topk(u, k)        # loop or single-pass threshold select
-    m'    = u zeroed at selected  # elementwise scatter within the tile
+    m'    = u zeroed at selected  # select against the selection mask
 
 ``selection`` picks the in-tile selection algorithm: "loop" (k masked
-argmax iterations, cheap for tiny k) or "threshold" (single-pass bisection
-select, O(32*C) independent of k — see ``repro.kernels.topk_select``).
+argmax iterations, cheap for tiny k) or "threshold" (bisection mask,
+compaction and a sorting network, independent of k — see
+``repro.kernels.topk_select``).
 Both emit bitwise-identical outputs.
 
-eta arrives via scalar prefetch (SMEM) so the same compiled kernel serves
-every step of a stepsize schedule.
+eta arrives as a (1, 1) f32 operand in SMEM (scalar memory), so the same
+compiled kernel serves every step of a stepsize schedule.
 """
 from __future__ import annotations
 
@@ -29,12 +30,13 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.topk_select import (
     DEFAULT_ROW_BLOCK,
     _auto_interpret,
-    _threshold_topk_tile,
-    _topk_loop,
+    _out_width,
+    _select_rows,
 )
 
 Array = jax.Array
@@ -42,18 +44,10 @@ Array = jax.Array
 
 def _fused_kernel(eta_ref, m_ref, g_ref, newm_ref, vals_ref, idx_ref, *,
                   k: int, selection: str):
-    eta = eta_ref[0, 0]
     m = m_ref[...]
-    g = g_ref[...]
-    u = m + eta.astype(m.dtype) * g.astype(m.dtype)
-    if selection == "threshold":
-        vals, idxs = _threshold_topk_tile(u, k)
-    else:
-        vals, idxs = _topk_loop(u, k)
-    Rb = u.shape[0]
-    rows = jax.lax.broadcasted_iota(jnp.int32, (Rb, k), 0)
-    new_m = u.at[rows, idxs].set(0)
-    newm_ref[...] = new_m
+    u = m + eta_ref[0, 0].astype(m.dtype) * g_ref[...].astype(m.dtype)
+    vals, idxs, selected = _select_rows(u, k, selection)
+    newm_ref[...] = jnp.where(selected, jnp.zeros_like(u), u)
     vals_ref[...] = vals
     idx_ref[...] = idxs
 
@@ -70,25 +64,27 @@ def fused_memsgd_pallas(
     assert R % row_block == 0, (R, row_block)
     assert k <= C, (k, C)
     grid = (R // row_block,)
+    width = _out_width(k, selection)
     kernel = functools.partial(_fused_kernel, k=k, selection=selection)
     eta_arr = jnp.asarray(eta, jnp.float32).reshape(1, 1)
-    return pl.pallas_call(
+    new_m, vals, idx = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),  # eta (SMEM-sized)
+            pl.BlockSpec(memory_space=pltpu.SMEM),  # eta, whole (1, 1)
             pl.BlockSpec((row_block, C), lambda i: (i, 0)),
             pl.BlockSpec((row_block, C), lambda i: (i, 0)),
         ],
         out_specs=[
             pl.BlockSpec((row_block, C), lambda i: (i, 0)),
-            pl.BlockSpec((row_block, k), lambda i: (i, 0)),
-            pl.BlockSpec((row_block, k), lambda i: (i, 0)),
+            pl.BlockSpec((row_block, width), lambda i: (i, 0)),
+            pl.BlockSpec((row_block, width), lambda i: (i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((R, C), m.dtype),
-            jax.ShapeDtypeStruct((R, k), m.dtype),
-            jax.ShapeDtypeStruct((R, k), jnp.int32),
+            jax.ShapeDtypeStruct((R, width), m.dtype),
+            jax.ShapeDtypeStruct((R, width), jnp.int32),
         ],
         interpret=_auto_interpret(interpret),
     )(eta_arr, m, g)
+    return new_m, vals[:, :k], idx[:, :k]

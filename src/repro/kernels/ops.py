@@ -1,13 +1,15 @@
 """Jitted public wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels execute in interpret mode — the kernel
-body runs as Python/jnp over the same BlockSpec tiling, which is what the
-tests validate against ``ref.py``. On a real TPU ``interpret=None``
-auto-detects the backend and compiles for real.
+On CPU the kernels execute in interpret mode — the kernel body runs as
+Python/jnp over the same BlockSpec tiling, which is what the tests
+validate against ``ref.py``. On a TPU ``interpret=None`` auto-detects the
+backend and compiles through Mosaic (``tests/test_tpu_compile.py``
+compiles each one for a described v5e chip; ``chip_smoke.py`` runs them
+on one).
 
 ``method`` picks the selection algorithm:
 
-* ``"loop"``      — k masked-argmax iterations, whole row in one VMEM tile.
+* ``"loop"``      — k masked row-max passes, whole row in one VMEM tile.
 * ``"threshold"`` — single-pass bisection select, column-tiled grid so C
   is not limited by VMEM (see ``topk_select.row_topk_tiled_pallas``).
 * ``"auto"``      — threshold for k above the backend's measured cutover

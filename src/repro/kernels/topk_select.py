@@ -4,46 +4,62 @@ Two selection algorithms share one output contract (top-|.|-k per row,
 emitted in decreasing-magnitude order, magnitude ties broken by LOWEST
 index — identical to ``repro.kernels.ref``):
 
-* ``loop`` — k iterations of masked row-argmax on an in-VMEM tile. O(k*C)
-  VPU work with k sequential dependent passes; cheap for tiny k.
+* ``loop`` — k passes of masked row-max, then min-index-of-max, over an
+  in-VMEM tile. O(k*C) VPU work.
 
-* ``threshold`` (single-pass) — per-row bisection on the *bit patterns* of
-  the f32 magnitudes finds the exact k-th magnitude threshold in <= 32
-  compare+count sweeps (O(32*C), independent of k), then ONE masked-cumsum
-  compaction emits the (value, index) pairs and an O(k^2) rank pass puts
-  them in the contract order. Because the bisection runs over int32
-  bitcasts of the magnitudes (monotone for non-negative floats) the
-  threshold is exact — outputs are bitwise-equal to the loop kernel.
+* ``threshold`` — per-row bisection on the *bit patterns* of the f32
+  magnitudes finds the exact k-th magnitude threshold in <= 32
+  compare+count sweeps, and a second bisection over the column index
+  keeps the lowest-index threshold ties, giving an exactly-k selection
+  mask (int32 bitcasts of non-negative floats are monotone, so the mask
+  is exact). A log-step compaction then moves the k selected pairs to a
+  narrow front of W lanes (k rounded up to a power of two, at least
+  128): a shift-and-add prefix count gives each kept entry its
+  displacement, and the entries move left by its bits, lowest first.
+  A bitonic network of lane rotations sorts the front into the contract
+  order. Nothing runs k times: the row takes O((32 + log2(C)) * C)
+  sweeps and the front O(log2(W)^2) compare-exchange stages, whatever
+  k is. Outputs are bitwise-equal to ``loop``; the kernels write all W
+  slots (the first k hold the selection) and the wrappers slice.
+
+Every step in a kernel body is an operation Mosaic lowers: elementwise
+compare/select against iotas, static lane rotations (``pltpu.roll``),
+aligned lane slices and row max/min/sum reductions. There is no gather,
+scatter, sort or cumsum in a kernel body: a value is read out of a row
+as the max of ``where(index == j, v, -inf)``, which returns v exactly
+(sign of zero included), and counts are f32 sums of 0/1 (exact below
+2**24).
 
 The threshold kernel also comes in a COLUMN-TILED form with grid
 ``(R // RB, C // CB)``: each (RB, CB) tile is merged into a running
 (RB, k) candidate buffer kept in the revisited output block (VMEM), so C
 no longer has to fit in a single VMEM tile and the whole selection remains
-a single pass over HBM. The merge invariant that makes tie-breaking exact:
-within the concatenated [candidates | tile] axis, entries of equal
-magnitude always appear in ascending-index order (candidates are kept
-sorted by (-|v|, index) and all candidate indices precede the tile's).
+a single pass over HBM. The candidate buffer is W lanes wide, so
+candidates and the tile's compacted front join at an aligned lane
+boundary and one 2W-lane sort orders them; each entry carries its global
+column index, and ties break on that index, not on position.
 
 Grid/BlockSpec layout (tiled form):
   grid  = (R // RB, C_padded // CB)         # last dim innermost
   x     : BlockSpec((RB, CB), (i, j) -> (i, j))
-  vals  : BlockSpec((RB, k),  (i, j) -> (i, 0))   # revisited accumulator
-  idx   : BlockSpec((RB, k),  (i, j) -> (i, 0))
+  vals  : BlockSpec((RB, W),  (i, j) -> (i, 0))   # revisited accumulator
+  idx   : BlockSpec((RB, W),  (i, j) -> (i, 0))
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 Array = jax.Array
 
 DEFAULT_ROW_BLOCK = 8
 # an (8, 4096) f32 tile is 128 KB — far under VMEM; wider tiles amortize
-# the per-merge fixed cost (bisection + rank sort) over more columns.
+# the per-merge fixed cost (bisection, compaction) over more columns.
 DEFAULT_COL_BLOCK = 4096
 # columns appended by jnp.pad / sentinel candidate slots carry this index;
 # larger than any real column index so they lose every magnitude tie.
@@ -53,14 +69,18 @@ _IDX_SENTINEL = 2**30  # python int: kernels must not capture traced consts
 _N_BISECT = 32
 # up to this k the k-pass argmax loop beats the fixed-cost threshold
 # select. Historical default — the per-backend MEASURED table in
-# ``repro.utils.platform.topk_loop_cutover`` supersedes it wherever a
-# backend entry exists (the interpret-mode CPU crossover sits at 4).
+# ``repro.utils.platform.topk_loop_cutover`` supersedes it.
 LOOP_MAX_K = 8
+
+# one selection input: (values, global column index, valid mask | None)
+Part = Tuple[Array, Array, Optional[Array]]
+# lane width of a TPU vreg: the threshold select's front is at least this
+_LANES = 128
 
 
 def _auto_interpret(interpret: Optional[bool]) -> bool:
     """Resolve ``interpret=None``: compiled lowering on TPU and GPU
-    (Mosaic / Triton), interpret fallback on CPU — with the
+    (Mosaic / Triton), interpret mode on CPU — with the
     ``REPRO_PALLAS_INTERPRET=0/1`` env override taking priority either
     way (see ``repro.utils.platform.pallas_interpret_default``). An
     explicit ``interpret=`` argument always wins."""
@@ -72,35 +92,14 @@ def _auto_interpret(interpret: Optional[bool]) -> bool:
 
 
 # ---------------------------------------------------------------------------
-# loop selection (fallback for tiny k)
+# shared row math (Mosaic-lowerable; also runs as plain jnp on any rank)
 # ---------------------------------------------------------------------------
 
 
-def _topk_loop(x: Array, k: int) -> Tuple[Array, Array]:
-    """k iterations of masked row-argmax on an in-VMEM tile."""
-    Rb, C = x.shape
-    absx = jnp.abs(x).astype(jnp.float32)
-    neg_inf = jnp.asarray(-jnp.inf, jnp.float32)
-    rows = jax.lax.broadcasted_iota(jnp.int32, (Rb,), 0)
-
-    def step(i, carry):
-        vals, idxs, absm = carry
-        j = jnp.argmax(absm, axis=1).astype(jnp.int32)  # (Rb,)
-        v = jnp.take_along_axis(x, j[:, None], axis=1)[:, 0]
-        vals = jax.lax.dynamic_update_slice(vals, v[:, None], (0, i))
-        idxs = jax.lax.dynamic_update_slice(idxs, j[:, None], (0, i))
-        absm = absm.at[rows, j].set(neg_inf)
-        return vals, idxs, absm
-
-    vals0 = jnp.zeros((Rb, k), x.dtype)
-    idxs0 = jnp.zeros((Rb, k), jnp.int32)
-    vals, idxs, _ = jax.lax.fori_loop(0, k, step, (vals0, idxs0, absx))
-    return vals, idxs
-
-
-# ---------------------------------------------------------------------------
-# threshold selection (single-pass) — shared math
-# ---------------------------------------------------------------------------
+def _count(mask: Array) -> Array:
+    """Per-row number of True entries, shape (..., 1) int32."""
+    return jnp.sum(mask.astype(jnp.float32), axis=-1,
+                   keepdims=True).astype(jnp.int32)
 
 
 def _mag_bits(v: Array, valid: Optional[Array] = None) -> Array:
@@ -113,31 +112,238 @@ def _mag_bits(v: Array, valid: Optional[Array] = None) -> Array:
     return bits
 
 
-def _kth_largest_bits(bits: Array, k: int) -> Array:
-    """Exact k-th largest of ``bits`` along the last axis via bisection.
+def _rcount(masks: Sequence[Array]) -> Array:
+    return functools.reduce(jnp.add, [_count(m) for m in masks])
 
-    Returns the largest t with count(bits >= t) >= k, shape (..., 1).
-    Requires at least k entries per row with bits > -1 when sentinels are
-    in play (guaranteed by the CB >= k / C >= k preconditions).
-    """
-    lo = jnp.full(bits.shape[:-1] + (1,), -1, jnp.int32)
-    hi = jnp.max(bits, axis=-1, keepdims=True)
+
+def _emit_ordered(V: Array, I: Array, k: int
+                  ) -> Tuple[Array, Array, Array]:
+    """The loop selection: k passes of row max of |V|, the lowest index
+    I holding it, that entry's value; the entry is then masked to -inf.
+    Returns (vals (..., k) in V's dtype, idx (..., k) int32, the mask of
+    the emitted entries)."""
+    lead = V.shape[:-1]
+    slot = jax.lax.broadcasted_iota(jnp.int32, lead + (k,), len(lead))
+    neg_inf = jnp.float32(-jnp.inf)
+    Vf = V.astype(jnp.float32)
+
+    def step(s, carry):
+        vals, idxs, mag = carry
+        m = jnp.max(mag, axis=-1, keepdims=True)
+        j = jnp.min(jnp.where(mag == m, I, _IDX_SENTINEL), axis=-1,
+                    keepdims=True)
+        v = jnp.max(jnp.where(I == j, Vf, neg_inf), axis=-1, keepdims=True)
+        vals = jnp.where(slot == s, v, vals)
+        idxs = jnp.where(slot == s, j, idxs)
+        return vals, idxs, jnp.where(I == j, neg_inf, mag)
+
+    vals0 = jnp.zeros(lead + (k,), jnp.float32)
+    idxs0 = jnp.zeros(lead + (k,), jnp.int32)
+    vals, idxs, mag = jax.lax.fori_loop(
+        0, k, step, (vals0, idxs0, jnp.abs(V).astype(jnp.float32)))
+    return vals.astype(V.dtype), idxs, mag == neg_inf
+
+
+def _kth_largest_bits(bits: Sequence[Array], k: int) -> Array:
+    """Exact k-th largest of the ``bits`` parts along the last axis via
+    bisection: the largest t with count(bits >= t) >= k, shape (..., 1).
+    Requires at least k entries per row with bits > -1."""
+    lo = jnp.full(bits[0].shape[:-1] + (1,), -1, jnp.int32)
+    hi = functools.reduce(
+        jnp.maximum, [jnp.max(b, axis=-1, keepdims=True) for b in bits])
 
     def body(_, carry):
         lo, hi = carry
-        mid = lo + (hi - lo + 1) // 2
-        cnt = jnp.sum((bits >= mid).astype(jnp.int32), axis=-1, keepdims=True)
-        ge = cnt >= k
+        mid = lo + jax.lax.shift_right_logical(hi - lo + 1, 1)
+        ge = _rcount([b >= mid for b in bits]) >= k
         return jnp.where(ge, mid, lo), jnp.where(ge, hi, mid - 1)
 
-    lo, hi = jax.lax.fori_loop(0, _N_BISECT, body, (lo, hi))
+    lo, _ = jax.lax.fori_loop(0, _N_BISECT, body, (lo, hi))
     return lo
+
+
+def _threshold_mask(bits: Sequence[Array], idx: Sequence[Array], k: int,
+                    idx_bound: int) -> List[Array]:
+    """Exactly-k per-row masks: every entry above the k-th magnitude,
+    plus the lowest-index ties at it. The tie cut is the smallest index
+    t with count(tie & index < t) == k - #above, found by bisection over
+    [0, idx_bound] (every real index is below ``idx_bound``)."""
+    tau = _kth_largest_bits(bits, k)
+    need = k - _rcount([b > tau for b in bits])
+    ties = [b == tau for b in bits]
+    lo = jnp.zeros_like(tau)
+    hi = jnp.full_like(tau, idx_bound)
+
+    def body(_, carry):
+        lo, hi = carry
+        mid = jax.lax.shift_right_logical(lo + hi, 1)
+        ge = _rcount([t & (I < mid) for t, I in zip(ties, idx)]) >= need
+        return jnp.where(ge, lo, mid + 1), jnp.where(ge, mid, hi)
+
+    cut, _ = jax.lax.fori_loop(
+        0, max(1, idx_bound.bit_length()), body, (lo, hi))
+    return [(b > tau) | (t & (I < cut))
+            for b, t, I in zip(bits, ties, idx)]
+
+
+def _roll(x: Array, shift) -> Array:
+    """``jnp.roll`` along the last axis by ``shift`` in [0, N) (a Python
+    int or a traced int32 scalar)."""
+    return pltpu.roll(x, shift, x.ndim - 1)
+
+
+def _pow2(e) -> Array:
+    """2 ** e for a traced int32 exponent."""
+    return jax.lax.shift_left(jnp.int32(1), e)
+
+
+def _prefix_count(mask: Array) -> Array:
+    """Inclusive running count of ``mask`` along the last axis, int32:
+    ceil(log2(N)) shift-and-add steps (Hillis-Steele), no cumsum."""
+    N = mask.shape[-1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, mask.shape, mask.ndim - 1)
+
+    def body(b, c):
+        s = _pow2(b)
+        return c + jnp.where(lane >= s, _roll(c, s), 0)
+
+    return jax.lax.fori_loop(0, (N - 1).bit_length(), body,
+                             mask.astype(jnp.int32))
+
+
+def _compact_front(sel: Array, payload: Sequence[Array], width: int
+                   ) -> List[Array]:
+    """The ``payload`` entries marked by ``sel`` moved to the front of
+    each row in index order, first ``width`` lanes kept (lanes past the
+    row's count hold leftovers).
+
+    A kept entry moves left by the number of dropped entries before it,
+    one bit of that displacement per step, lowest bit first: kept
+    entries keep their order and their displacements do not decrease
+    along the row, so no two ever land on one lane."""
+    N = sel.shape[-1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, sel.shape, sel.ndim - 1)
+
+    def body(b, carry):
+        d, payload = carry
+        s = _pow2(b)
+        d_in = _roll(d, N - s)  # the entry s lanes to the right
+        move = (lane < N - s) & (d_in >= 0) & (
+            (jax.lax.shift_right_logical(d_in, b) & 1) == 1)
+        stay = (d >= 0) & ((jax.lax.shift_right_logical(d, b) & 1) == 0)
+        d = jnp.where(move, d_in, jnp.where(stay, d, -1))
+        return d, [jnp.where(move, _roll(x, N - s), x) for x in payload]
+
+    # displacement of each kept entry; -1 marks an empty lane
+    d = jnp.where(sel, lane + 1 - _prefix_count(sel), -1)
+    _, payload = jax.lax.fori_loop(0, (N - 1).bit_length(), body,
+                                   (d, list(payload)))
+    return [x[..., :width] for x in payload]
+
+
+def _front_width(k: int) -> int:
+    """Lanes of the threshold select's sorted front: k rounded up to a
+    power of two, at least one vreg row (128 lanes)."""
+    return max(_LANES, 1 << (k - 1).bit_length())
+
+
+def _out_width(k: int, selection: str) -> int:
+    """Slots a kernel writes per row: k for the loop, the whole front for
+    the threshold select (its first k slots hold the selection)."""
+    return _front_width(k) if selection == "threshold" else k
+
+
+def _front(sel: Array, V: Array, I: Array, B: Array, width: int
+           ) -> Tuple[Array, Array, Array]:
+    """The entries marked by ``sel`` on ``width`` lanes (compacted when
+    the row is wider, padded when narrower); every other lane is made
+    invalid: bits -1, index ``_IDX_SENTINEL``."""
+    N = sel.shape[-1]
+    if N > width:
+        V, I, B = _compact_front(sel, [V, I, B], width)
+        lane = jax.lax.broadcasted_iota(jnp.int32, I.shape, I.ndim - 1)
+        sel = lane < _count(sel)
+    elif N < width:  # rows narrower than one front
+        pad = [(0, 0)] * (sel.ndim - 1) + [(0, width - N)]
+        V, I, B, sel = (jnp.pad(x, pad) for x in (V, I, B, sel))
+    return (V, jnp.where(sel, I, _IDX_SENTINEL),
+            jnp.where(sel, B, jnp.int32(-1)))
+
+
+def _sort_front(V: Array, I: Array, B: Array) -> Tuple[Array, Array, Array]:
+    """Bitonic sort of each row (a power-of-two number of lanes) into the
+    contract order: magnitude bits descending, then index ascending, so
+    invalid entries (bits -1) go last. Compare-exchange with the partner
+    lane ``lane ^ j`` read through two lane rotations."""
+    W = B.shape[-1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, B.shape, B.ndim - 1)
+
+    def exchange(s, r, carry):
+        V, I, B = carry
+        j = _pow2(s - 1 - r)
+        up = (lane & _pow2(s)) == 0  # this block sorts in contract order
+        lower = (lane & j) == 0
+
+        def partner(x):
+            return jnp.where(lower, _roll(x, W - j), _roll(x, j))
+
+        pV, pI, pB = partner(V), partner(I), partner(B)
+        first = (B > pB) | ((B == pB) & ((I < pI) | ((I == pI) & lower)))
+        keep = first == (lower == up)
+        return (jnp.where(keep, V, pV), jnp.where(keep, I, pI),
+                jnp.where(keep, B, pB))
+
+    carry = (V, I, B)
+    for s in range(1, W.bit_length()):  # block sizes 2, 4, ..., W
+        carry = jax.lax.fori_loop(
+            0, s, functools.partial(exchange, jnp.int32(s)), carry)
+    return carry
+
+
+def _threshold_select(parts: Sequence[Part], k: int, idx_bound: int
+                      ) -> Tuple[Array, Array, List[Array]]:
+    """Threshold top-|.|-k over the union of ``parts`` (every index below
+    ``idx_bound``) in contract order.
+
+    Returns (vals in the first part's dtype and idx, both
+    ``_front_width(k)`` wide, the first k slots holding the selection;
+    one mask per part marking the selected entries). Needs at least k
+    valid entries per row."""
+    bits = [_mag_bits(V, valid) for V, _, valid in parts]
+    sel = _threshold_mask(bits, [I for _, I, _ in parts], k, idx_bound)
+    width = _front_width(k)
+    fronts = [_front(s, V.astype(jnp.float32), I, B, width)
+              for (V, I, _), B, s in zip(parts, bits, sel)]
+    V, I, B = (jnp.concatenate(xs, axis=-1) if len(xs) > 1 else xs[0]
+               for xs in zip(*fronts))
+    V, I, _ = _sort_front(V, I, B)
+    return V[..., :width].astype(parts[0][0].dtype), I[..., :width], sel
+
+
+def _select_rows(x: Array, k: int, selection: str
+                 ) -> Tuple[Array, Array, Array]:
+    """Top-k of every row of ``x`` (..., C), C >= k. Returns (vals, idx,
+    ``_out_width(k, selection)`` slots per row with the selection in the
+    first k, and the selected mask shaped like x)."""
+    I = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+    if selection == "loop":
+        return _emit_ordered(x, I, k)
+    if selection != "threshold":
+        raise ValueError(f"unknown selection {selection!r}")
+    vals, idx, (selected,) = _threshold_select([(x, I, None)], k,
+                                               x.shape[-1])
+    return vals, idx, selected
+
+
+# ---------------------------------------------------------------------------
+# jnp twin of the threshold select (plain XLA, never a kernel body)
+# ---------------------------------------------------------------------------
 
 
 def _select_mask(bits: Array, tau: Array, k: int) -> Array:
     """Exactly-k per-row mask: all > tau, plus the first (k - #gt) ties in
     axis order. Correct iff equal magnitudes appear in ascending-index
-    order along the axis (see module docstring)."""
+    order along the axis."""
     gt = bits > tau
     eq = bits == tau
     n_gt = jnp.sum(gt.astype(jnp.int32), axis=-1, keepdims=True)
@@ -168,7 +374,7 @@ def _compact_selected(sel: Array, k: int) -> Array:
 
 
 def _order_pairs(cv: Array, ci: Array, cb: Array) -> Tuple[Array, Array]:
-    """Permute compacted (Rb, k) pairs into the contract's (-|v|, index)
+    """Permute compacted (..., k) pairs into the contract's (-|v|, index)
     order: O(k^2) rank + one-hot permutation (exact — each output sums
     exactly one nonzero term, so even bf16 values pass through bitwise)."""
     k = cv.shape[-1]
@@ -186,26 +392,20 @@ def _order_pairs(cv: Array, ci: Array, cb: Array) -> Tuple[Array, Array]:
     return out_v, out_i
 
 
-def _threshold_select(V: Array, I: Array, valid: Optional[Array], k: int
-                      ) -> Tuple[Array, Array]:
-    """Top-|.|-k of (V, I) pairs along the last axis, emitted sorted by
-    (-|v|, index). Entries of equal magnitude must appear in
-    ascending-index order along the axis."""
-    bits = _mag_bits(V, valid)
-    tau = _kth_largest_bits(bits, k)
-    sel = _select_mask(bits, tau, k)
-    n_sel = _compact_selected(sel, k)  # (..., k) positions, axis order
-    cv = jnp.take_along_axis(V, n_sel, axis=-1)
+def threshold_topk_jnp(x: Array, k: int) -> Tuple[Array, Array]:
+    """Threshold top-k of every row of ``x`` (..., C) in plain jnp: the
+    same exact bisection threshold as the kernels (O(32*C), independent
+    of k), then one masked-cumsum compaction (gathers along the row) and
+    an O(k^2) rank pass into the contract order. For XLA outside Mosaic,
+    where the partition-safe ``core.distributed`` selection runs it."""
+    I = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+    bits = _mag_bits(x)
+    tau = _kth_largest_bits([bits], k)
+    n_sel = _compact_selected(_select_mask(bits, tau, k), k)
+    cv = jnp.take_along_axis(x, n_sel, axis=-1)
     ci = jnp.take_along_axis(I, n_sel, axis=-1)
     cb = jnp.take_along_axis(bits, n_sel, axis=-1)
     return _order_pairs(cv, ci, cb)
-
-
-def _threshold_topk_tile(x: Array, k: int) -> Tuple[Array, Array]:
-    """Single-pass top-k of a resident (Rb, C) tile (C >= k)."""
-    Rb, C = x.shape
-    I = jax.lax.broadcasted_iota(jnp.int32, (Rb, C), 1)
-    return _threshold_select(x, I, None, k)
 
 
 def mask_live_k(vals: Array, idx: Array, k_live) -> Tuple[Array, Array]:
@@ -252,17 +452,14 @@ def mask_live_k(vals: Array, idx: Array, k_live) -> Tuple[Array, Array]:
 
 
 def _topk_kernel(x_ref, vals_ref, idx_ref, *, k: int, selection: str):
-    x = x_ref[...]
-    if selection == "threshold":
-        vals, idxs = _threshold_topk_tile(x, k)
-    else:
-        vals, idxs = _topk_loop(x, k)
+    vals, idxs, _ = _select_rows(x_ref[...], k, selection)
     vals_ref[...] = vals
     idx_ref[...] = idxs
 
 
-def _topk_tiled_kernel(x_ref, vals_ref, idx_ref, *, k: int, col_block: int):
-    """Merge one (RB, CB) tile into the (RB, k) candidate accumulator."""
+def _topk_tiled_kernel(x_ref, vals_ref, idx_ref, *, k: int, col_block: int,
+                       n_cols: int):
+    """Merge one (RB, CB) tile into the (RB, W) candidate accumulator."""
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -271,15 +468,15 @@ def _topk_tiled_kernel(x_ref, vals_ref, idx_ref, *, k: int, col_block: int):
         idx_ref[...] = jnp.full(idx_ref.shape, _IDX_SENTINEL, jnp.int32)
 
     x = x_ref[...]
-    Rb = x.shape[0]
     tile_i = j * col_block + jax.lax.broadcasted_iota(
         jnp.int32, x.shape, 1
     )
     cand_v, cand_i = vals_ref[...], idx_ref[...]
-    V = jnp.concatenate([cand_v, x], axis=1)
-    I = jnp.concatenate([cand_i, tile_i], axis=1)
-    valid = I < _IDX_SENTINEL  # sentinel candidate slots never compete
-    vals_ref[...], idx_ref[...] = _threshold_select(V, I, valid, k)
+    # sentinel candidate slots never compete
+    parts = [(cand_v, cand_i, cand_i < _IDX_SENTINEL), (x, tile_i, None)]
+    vals, idxs, _ = _threshold_select(parts, k, n_cols)
+    vals_ref[...] = vals
+    idx_ref[...] = idxs
 
 
 def row_topk_pallas(
@@ -295,21 +492,23 @@ def row_topk_pallas(
     assert R % row_block == 0, (R, row_block)
     assert k <= C, (k, C)
     grid = (R // row_block,)
+    width = _out_width(k, selection)
     kernel = functools.partial(_topk_kernel, k=k, selection=selection)
-    return pl.pallas_call(
+    vals, idx = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[pl.BlockSpec((row_block, C), lambda i: (i, 0))],
         out_specs=[
-            pl.BlockSpec((row_block, k), lambda i: (i, 0)),
-            pl.BlockSpec((row_block, k), lambda i: (i, 0)),
+            pl.BlockSpec((row_block, width), lambda i: (i, 0)),
+            pl.BlockSpec((row_block, width), lambda i: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((R, k), x.dtype),
-            jax.ShapeDtypeStruct((R, k), jnp.int32),
+            jax.ShapeDtypeStruct((R, width), x.dtype),
+            jax.ShapeDtypeStruct((R, width), jnp.int32),
         ],
         interpret=_auto_interpret(interpret),
     )(x)
+    return vals[:, :k], idx[:, :k]
 
 
 def row_topk_tiled_pallas(
@@ -330,18 +529,21 @@ def row_topk_tiled_pallas(
     if pad:
         x = jnp.pad(x, ((0, 0), (0, pad)))
     grid = (R // row_block, (C + pad) // cb)
-    kernel = functools.partial(_topk_tiled_kernel, k=k, col_block=cb)
-    return pl.pallas_call(
+    width = _front_width(k)
+    kernel = functools.partial(_topk_tiled_kernel, k=k, col_block=cb,
+                               n_cols=C + pad)
+    vals, idx = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[pl.BlockSpec((row_block, cb), lambda i, j: (i, j))],
         out_specs=[
-            pl.BlockSpec((row_block, k), lambda i, j: (i, 0)),
-            pl.BlockSpec((row_block, k), lambda i, j: (i, 0)),
+            pl.BlockSpec((row_block, width), lambda i, j: (i, 0)),
+            pl.BlockSpec((row_block, width), lambda i, j: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((R, k), x.dtype),
-            jax.ShapeDtypeStruct((R, k), jnp.int32),
+            jax.ShapeDtypeStruct((R, width), x.dtype),
+            jax.ShapeDtypeStruct((R, width), jnp.int32),
         ],
         interpret=_auto_interpret(interpret),
     )(x)
+    return vals[:, :k], idx[:, :k]

@@ -271,8 +271,7 @@ def make_train_step(model, mesh, tc: TrainConfig):
     def _constrain_params(params):
         # params: full (model-auto) view; memory leaves (1, *shape) local
         return jax.tree.map(
-            lambda p, s: jax.lax.with_sharding_constraint(
-                p, NamedSharding(mesh, s)),
+            lambda p, s: jax.lax.with_sharding_constraint(p, s),
             params, pspecs, is_leaf=None,
         )
 
@@ -1045,22 +1044,36 @@ def _sync_from_args(ap, args) -> SyncConfig:
 
 
 def main():
-    """CLI: train an assigned architecture's SMOKE variant end-to-end.
+    """CLI: train an assigned architecture end to end.
 
-    Full-size configs are exercised via ``repro.launch.dryrun`` (this
-    container is CPU-only); this driver proves the full stack on the
-    reduced variants:  python -m repro.launch.train --arch qwen3-4b
+    ``--size smoke`` (the default, for CPU runs) trains the reduced
+    variant; ``--size full`` the published config, with ``--layers N``
+    cutting depth only (widths, heads, head_dim, LoRA ranks and vocab
+    stay published) so it fits one accelerator:
+
+        python -m repro.launch.train --arch qwen3-4b
+        python -m repro.launch.train --arch rwkv6-3b --size full \
+            --layers 2 --preset topk --bucketed --wire packed --seq 512
+
+    The persistent compile cache follows ``JAX_COMPILATION_CACHE_DIR``
+    when set, else ``<repo>/.jax_cache``.
     """
     import argparse
 
     from repro.checkpoint import Checkpointer
-    from repro.configs import ARCH_IDS, MESHES, get_smoke_config
+    from repro.configs import ARCH_IDS, MESHES, sized_config
     from repro.data import token_batches
     from repro.data.pipeline import ShardedBatcher
     from repro.models import build_model
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-4b", choices=ARCH_IDS)
+    ap.add_argument("--size", default="smoke", choices=("smoke", "full"),
+                    help="smoke: the reduced same-family variant (CPU); "
+                         "full: the published config")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="cut depth to N layers (widths, heads, head_dim, "
+                         "LoRA ranks and vocab are never cut)")
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--optimizer", default="memsgd")
     ap.add_argument("--eta", type=float, default=0.5)
@@ -1167,6 +1180,9 @@ def main():
                          "section of wire checkpoints")
     args = ap.parse_args()
 
+    from repro.utils.platform import enable_compile_cache
+
+    enable_compile_cache()
     if args.platform is not None:
         # before any backend use (device_count below initializes the
         # client, which reads XLA_FLAGS once)
@@ -1190,7 +1206,7 @@ def main():
     batch_axes = (
         ("pod", "data") if "pod" in mesh.axis_names else ("data",)
     )
-    cfg = get_smoke_config(args.arch)
+    cfg = sized_config(args.arch, args.size, args.layers)
     model = build_model(cfg)
     refresh = None
     if args.pod_refresh_every > 0:

@@ -1,85 +1,32 @@
-"""jax version-compatibility shims.
+"""Mesh and shard_map wrappers that fill in this codebase's defaults.
 
-The framework targets the current jax API (``jax.sharding.AxisType``,
-``jax.make_mesh(..., axis_types=...)``, ``jax.shard_map``) but must also run
-on older releases (this container ships 0.4.x) where those names live
-elsewhere or don't exist:
-
-* ``AxisType``   — absent before 0.5; meshes are implicitly Auto.
-* ``make_mesh``  — older signature has no ``axis_types`` kwarg.
-* ``shard_map``  — ``jax.experimental.shard_map.shard_map`` with the manual
-  axes expressed through the complementary ``auto=`` frozenset and
-  ``check_vma`` spelled ``check_rep``.
-
-Everything that builds meshes or shard_maps goes through this module so
-version drift is handled in exactly one place.
+* ``make_mesh`` — ``jax.make_mesh`` with every axis ``AxisType.Auto``
+  unless ``axis_types`` says otherwise (the only mode this codebase
+  uses; jax's own default is Explicit).
+* ``shard_map`` — ``jax.shard_map`` with ``check_vma=False`` and the
+  manual axes accepted as any iterable (``None``: every mesh axis).
 """
 from __future__ import annotations
 
-import inspect
-
 import jax
-
-try:  # jax >= 0.5
-    from jax.sharding import AxisType  # type: ignore  # noqa: F401
-except ImportError:  # pragma: no cover - depends on installed jax
-
-    class AxisType:  # minimal stand-in; old meshes are implicitly Auto
-        Auto = "auto"
-        Explicit = "explicit"
-        Manual = "manual"
-
-
-_MAKE_MESH_AXIS_TYPES = (
-    "axis_types" in inspect.signature(jax.make_mesh).parameters
-)
+from jax.sharding import AxisType
 
 
 def make_mesh(axis_shapes, axis_names, *, axis_types=None, devices=None):
-    """``jax.make_mesh`` that tolerates the missing ``axis_types`` kwarg.
-
-    ``axis_types=None`` means all-Auto (the only mode this codebase uses;
-    older jax without the kwarg behaves that way implicitly).
-    """
+    """``jax.make_mesh`` with all-Auto axes by default."""
+    if axis_types is None:
+        axis_types = (AxisType.Auto,) * len(axis_names)
     kw = {} if devices is None else {"devices": devices}
-    if _MAKE_MESH_AXIS_TYPES:
-        if axis_types is None:
-            axis_types = (AxisType.Auto,) * len(axis_names)
-        return jax.make_mesh(axis_shapes, axis_names, axis_types=axis_types,
-                             **kw)
-    return jax.make_mesh(axis_shapes, axis_names, **kw)
+    return jax.make_mesh(axis_shapes, axis_names, axis_types=axis_types,
+                         **kw)
 
 
-if hasattr(jax, "shard_map"):
-
-    def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
-                  check_vma=False):
-        kw = {}
-        if axis_names is not None:
-            kw["axis_names"] = set(axis_names)
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma, **kw)
-
-else:  # pragma: no cover - depends on installed jax
-    from jax.experimental.shard_map import shard_map as _shard_map_legacy
-
-    def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
-                  check_vma=False):
-        manual = frozenset(axis_names) if axis_names is not None else (
-            frozenset(mesh.axis_names))
-        auto = frozenset(mesh.axis_names) - manual
-        return _shard_map_legacy(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=bool(check_vma),
-                                 auto=auto)
+def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
+              check_vma=False):
+    """``jax.shard_map`` with ``check_vma=False`` by default."""
+    kw = {} if axis_names is None else {"axis_names": set(axis_names)}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma, **kw)
 
 
-if hasattr(jax.lax, "axis_size"):
-    axis_size = jax.lax.axis_size
-else:  # pragma: no cover - depends on installed jax
-
-    def axis_size(axis_name):
-        """Size of a mapped mesh axis (usable inside shard_map)."""
-        return jax.lax.psum(1, axis_name)
-
-
-__all__ = ["AxisType", "make_mesh", "shard_map", "axis_size"]
+__all__ = ["make_mesh", "shard_map"]

@@ -21,12 +21,17 @@ stack configure itself for it":
   ``REPRO_PALLAS_INTERPRET=0/1`` (CI on GPU runners can force
   interpret-off; a CPU box can smoke the compiled path's plumbing).
 
+* ``enable_compile_cache`` — JAX's persistent compilation cache:
+  ``JAX_COMPILATION_CACHE_DIR`` when it is set (jax reads it itself),
+  otherwise the fixed ``<repo>/.jax_cache`` of this checkout.
+
 Nothing here imports the kernels (they import us), so the module stays
 import-cycle-free and safe to use before any JAX computation runs.
 """
 from __future__ import annotations
 
 import os
+from pathlib import Path
 from typing import Optional
 
 # XLA perf flags for CUDA backends (bayespec-style setup): run
@@ -46,19 +51,28 @@ GPU_PERF_FLAGS = (
 )
 
 # Largest k at which the k-pass argmax loop still beats the fixed-cost
-# (O(32*C)) bisection threshold select, per backend. The CPU entry is
-# MEASURED on the interpret-mode reference machine (BENCH_topk.json
-# ``cutover`` sweep: at k=8 the loop is already ~1.4x slower, at k<=4
-# it wins or ties); the TPU/GPU entries keep the historical
-# ``LOOP_MAX_K = 8`` until a hardware sweep refreshes them.
+# threshold select (bisection, compaction, sorting network; flat in k),
+# per backend. MEASURED: the CPU entry on the interpret-mode reference
+# machine (BENCH_topk.json ``cutover`` sweep: the loop is ~1.7x faster
+# at k=4 and ~1.2x slower at k=8); the TPU entry on a v5e chip
+# (``python chip_smoke.py --kernel-sweep`` at 65,536 x 1024: the loop
+# takes ~2.76 ms per unit of k, the threshold a flat ~90 ms, so they
+# tie at k=32 and the threshold is 2x faster at k=64). The GPU entry
+# keeps the historical ``LOOP_MAX_K = 8`` until a sweep refreshes it.
+# A backend missing from the table is an error, not a default.
 TOPK_LOOP_CUTOVER = {
     "cpu": 4,
     "gpu": 8,
-    "tpu": 8,
+    "tpu": 32,
 }
-_CUTOVER_FALLBACK = 8
 
 ENV_INTERPRET = "REPRO_PALLAS_INTERPRET"
+
+ENV_COMPILE_CACHE = "JAX_COMPILATION_CACHE_DIR"
+# fixed path inside the checkout (src/repro/utils/platform.py -> repo
+# root): the cache key includes nothing run-specific, so a later run of
+# the same checkout finds what an earlier one compiled
+REPO_COMPILE_CACHE = Path(__file__).resolve().parents[3] / ".jax_cache"
 
 
 def _merge_xla_flags(existing: str, new_flags) -> str:
@@ -102,6 +116,21 @@ def setup_platform(platform: Optional[str] = None,
             "jax_platform_name", "gpu" if platform == "cuda" else platform)
 
 
+def enable_compile_cache() -> str:
+    """Turn on the persistent compilation cache; returns its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is used as is and nothing
+    is set here. Otherwise the cache goes to ``REPO_COMPILE_CACHE``."""
+    env = os.environ.get(ENV_COMPILE_CACHE)
+    if env:
+        return env
+    import jax
+
+    REPO_COMPILE_CACHE.mkdir(parents=True, exist_ok=True)
+    jax.config.update("jax_compilation_cache_dir", str(REPO_COMPILE_CACHE))
+    return str(REPO_COMPILE_CACHE)
+
+
 def backend() -> str:
     """The active JAX backend name ("cpu" / "gpu" / "tpu")."""
     import jax
@@ -112,7 +141,11 @@ def backend() -> str:
 def topk_loop_cutover(backend_name: Optional[str] = None) -> int:
     """Per-backend loop-vs-threshold top-k cutover (see table above)."""
     b = backend_name if backend_name is not None else backend()
-    return TOPK_LOOP_CUTOVER.get(b, _CUTOVER_FALLBACK)
+    if b not in TOPK_LOOP_CUTOVER:
+        raise ValueError(
+            f"no top-k loop/threshold cutover for backend {b!r}; known: "
+            f"{sorted(TOPK_LOOP_CUTOVER)}")
+    return TOPK_LOOP_CUTOVER[b]
 
 
 def pallas_interpret_default(backend_name: Optional[str] = None) -> bool:

@@ -1,53 +1,30 @@
-"""Branch coverage for the ``repro.utils.compat`` version shims.
-
-Only one jax version is installed, so the other arm of each shim can't
-run natively; the legacy/modern arms are exercised by reloading the
-module under monkeypatched ``jax`` attributes and asserting the wrapper
-translates kwargs correctly (``axis_types`` dropped, ``check_vma`` ->
-``check_rep`` + complementary ``auto=``, ``axis_size`` -> ``psum``).
-"""
-import importlib
-import sys
-import types
-
+"""The ``repro.utils.compat`` mesh/shard_map wrappers: the defaults they
+fill in (all-Auto mesh axes, ``check_vma=False``) and that meshes and
+shard_maps built through them run end to end."""
 import numpy as np
-import pytest
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType
 
 import repro.utils.compat as compat
 
 
-@pytest.fixture
-def reloaded_compat():
-    """Yield (monkeypatch, module); whatever the test reloads, the
-    teardown reload restores the real-jax branches.
-
-    Owns its MonkeyPatch instead of using the fixture: the patches must
-    be undone BEFORE the restoring reload (the builtin fixture tears
-    down after this one, which would re-capture the fakes)."""
-    mp = pytest.MonkeyPatch()
-    yield mp, compat
-    mp.undo()
-    importlib.reload(compat)
-
-
-# ---------------------------------------------------------------------------
-# whichever branch is installed must actually work end to end
-
-
 def test_axis_type_has_modes():
-    for mode in ("Auto", "Explicit", "Manual"):
-        assert hasattr(compat.AxisType, mode)
+    """Every axis is Auto unless the caller names other modes."""
+    n = jax.device_count()
+    mesh = compat.make_mesh((n, 1), ("data", "model"))
+    assert tuple(mesh.axis_types) == (AxisType.Auto, AxisType.Auto)
+    mesh = compat.make_mesh((n,), ("data",),
+                            axis_types=(AxisType.Explicit,))
+    assert tuple(mesh.axis_types) == (AxisType.Explicit,)
 
 
 def test_make_mesh_builds_real_mesh():
     n = jax.device_count()
     mesh = compat.make_mesh((n,), ("data",))
     assert tuple(mesh.axis_names) == ("data",)
-    mesh = compat.make_mesh((n,), ("data",),
-                            axis_types=(compat.AxisType.Auto,))
+    mesh = compat.make_mesh((n,), ("data",), axis_types=(AxisType.Auto,))
     assert tuple(mesh.axis_names) == ("data",)
 
 
@@ -71,106 +48,8 @@ def test_axis_size_inside_shard_map():
     n = jax.device_count()
     mesh = compat.make_mesh((n,), ("data",))
     P = jax.sharding.PartitionSpec
-    f = compat.shard_map(lambda x: x * compat.axis_size("data"),
+    f = compat.shard_map(lambda x: x * jax.lax.axis_size("data"),
                          mesh=mesh, in_specs=P("data"),
-                         out_specs=P("data"))
+                         out_specs=P("data"), axis_names=("data",))
     x = jnp.ones((n,), jnp.float32)
     np.testing.assert_allclose(np.asarray(f(x)), np.full((n,), float(n)))
-
-
-# ---------------------------------------------------------------------------
-# legacy arms (jax without AxisType / axis_types kwarg / jax.shard_map /
-# jax.lax.axis_size), simulated via reload under monkeypatched jax
-
-
-def test_legacy_branches(reloaded_compat):
-    monkeypatch, mod = reloaded_compat
-    mesh_calls = []
-    sm_calls = []
-    psum_calls = []
-
-    def old_make_mesh(axis_shapes, axis_names, *, devices=None):
-        mesh_calls.append((axis_shapes, axis_names, devices))
-        return "legacy-mesh"
-
-    legacy_sm = types.ModuleType("jax.experimental.shard_map")
-
-    def legacy_shard_map(f, *, mesh, in_specs, out_specs, check_rep, auto):
-        sm_calls.append({"mesh": mesh, "in_specs": in_specs,
-                         "out_specs": out_specs, "check_rep": check_rep,
-                         "auto": auto})
-        return f
-
-    legacy_sm.shard_map = legacy_shard_map
-
-    monkeypatch.delattr(jax.sharding, "AxisType", raising=False)
-    monkeypatch.setattr(jax, "make_mesh", old_make_mesh)
-    monkeypatch.delattr(jax, "shard_map", raising=False)
-    monkeypatch.setitem(sys.modules, "jax.experimental.shard_map", legacy_sm)
-    monkeypatch.delattr(jax.lax, "axis_size", raising=False)
-    monkeypatch.setattr(jax.lax, "psum",
-                        lambda v, axis: psum_calls.append((v, axis)))
-
-    importlib.reload(mod)
-
-    # AxisType stand-in
-    assert mod.AxisType.Auto == "auto"
-    assert not mod._MAKE_MESH_AXIS_TYPES
-
-    # make_mesh: axis_types silently dropped for the old signature
-    assert mod.make_mesh((2,), ("data",),
-                         axis_types=("auto",)) == "legacy-mesh"
-    assert mesh_calls == [((2,), ("data",), None)]
-
-    # shard_map: manual axes become the complementary auto= frozenset,
-    # check_vma becomes check_rep
-    fake_mesh = types.SimpleNamespace(axis_names=("pod", "data"))
-    fn = lambda x: x  # noqa: E731
-    out = mod.shard_map(fn, mesh=fake_mesh, in_specs="i", out_specs="o",
-                        axis_names=("data",), check_vma=True)
-    assert out is fn
-    assert sm_calls[-1]["auto"] == frozenset({"pod"})
-    assert sm_calls[-1]["check_rep"] is True
-
-    # default: all mesh axes manual -> empty auto=
-    mod.shard_map(fn, mesh=fake_mesh, in_specs="i", out_specs="o")
-    assert sm_calls[-1]["auto"] == frozenset()
-    assert sm_calls[-1]["check_rep"] is False
-
-    # axis_size falls back to psum(1, axis)
-    mod.axis_size("data")
-    assert psum_calls == [(1, "data")]
-
-
-def test_modern_branches_fill_defaults(reloaded_compat):
-    monkeypatch, mod = reloaded_compat
-    mesh_calls = []
-    sm_calls = []
-
-    def new_make_mesh(axis_shapes, axis_names, *, axis_types=None,
-                      devices=None):
-        mesh_calls.append((axis_shapes, axis_names, axis_types, devices))
-        return "modern-mesh"
-
-    def new_shard_map(f, *, mesh, in_specs, out_specs, check_vma,
-                      axis_names=None):
-        sm_calls.append({"check_vma": check_vma, "axis_names": axis_names})
-        return f
-
-    monkeypatch.setattr(jax, "make_mesh", new_make_mesh)
-    monkeypatch.setattr(jax, "shard_map", new_shard_map, raising=False)
-
-    importlib.reload(mod)
-
-    assert mod._MAKE_MESH_AXIS_TYPES
-    # axis_types=None expands to an all-Auto tuple, one per axis
-    mod.make_mesh((1, 2), ("pod", "data"))
-    assert mesh_calls[-1][2] == (mod.AxisType.Auto, mod.AxisType.Auto)
-
-    fn = lambda x: x  # noqa: E731
-    mod.shard_map(fn, mesh="m", in_specs="i", out_specs="o",
-                  axis_names=("data",), check_vma=True)
-    assert sm_calls[-1] == {"check_vma": True, "axis_names": {"data"}}
-    # axis_names omitted entirely when None (jax fills every axis)
-    mod.shard_map(fn, mesh="m", in_specs="i", out_specs="o")
-    assert sm_calls[-1] == {"check_vma": False, "axis_names": None}

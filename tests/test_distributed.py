@@ -17,20 +17,6 @@ import pytest
 
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
-# jax < 0.5 (no jax.shard_map) routes through the legacy
-# experimental.shard_map whose partial-auto mode crashes XLA's SPMD
-# partitioner (Check failed: sharding.IsManualSubgroup()) whenever the
-# auto "model" axis has size > 1. Single-axis and model=1 meshes work.
-# strict: on a fixed jax the condition is False and the mark inert; on
-# legacy jax an unexpected PASS must surface as a failure (XPASS), not
-# rot silently after a container upgrade.
-legacy_partial_auto = pytest.mark.xfail(
-    not hasattr(jax, "shard_map"),
-    reason="legacy shard_map partial-auto + sharded model axis crashes XLA",
-    strict=True,
-)
-
-
 def _run_subprocess(body: str) -> dict:
     """Run `body` with 8 fake devices; it must print one JSON line."""
     script = textwrap.dedent(
@@ -86,7 +72,6 @@ def test_sync_col_axes_rules():
 
 
 @pytest.mark.slow
-@legacy_partial_auto
 def test_distributed_memsgd_loss_decreases():
     rec = _run_subprocess(
         """
@@ -182,7 +167,6 @@ def test_distributed_sparse_sync_no_dense_allreduce():
 
 
 @pytest.mark.slow
-@legacy_partial_auto
 def test_hierarchical_matches_flat_when_pod_ratio_full():
     """With pod re-compression disabled (pod_ratio=1.0 => k_pod = full
     row), hierarchical == flat sparse_allgather updates after one step."""

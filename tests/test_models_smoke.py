@@ -5,7 +5,8 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.configs import ARCH_IDS, SHAPES, get_config, get_smoke_config
+from repro.configs import (ARCH_IDS, SHAPES, get_config,
+                           get_smoke_config, sized_config)
 from repro.models import build_model
 
 
@@ -116,6 +117,18 @@ def test_full_config_matches_assignment(arch):
         assert cfg.n_kv_heads == 1
     if arch == "yi-9b":
         assert cfg.n_kv_heads == 4
+
+
+@pytest.mark.parametrize("size", ["smoke", "full"])
+def test_sized_config_cuts_depth_only(size):
+    """The train CLI's --size/--layers: depth is the only cut."""
+    base = (get_config if size == "full" else get_smoke_config)("rwkv6-3b")
+    cut = sized_config("rwkv6-3b", size, layers=1)
+    assert cut.n_layers == 1
+    assert cut.replace(n_layers=base.n_layers) == base
+    assert sized_config("rwkv6-3b", size) == base
+    with pytest.raises(ValueError, match="layers"):
+        sized_config("rwkv6-3b", size, layers=0)
 
 
 def test_shape_configs_match_assignment():

@@ -60,8 +60,9 @@ def test_kernel_auto_interpret_consults_env(monkeypatch):
 def test_cutover_table_per_backend():
     assert pf.topk_loop_cutover("cpu") == pf.TOPK_LOOP_CUTOVER["cpu"]
     assert pf.topk_loop_cutover("tpu") == pf.TOPK_LOOP_CUTOVER["tpu"]
-    # unknown backends get the conservative fallback, never a KeyError
-    assert pf.topk_loop_cutover("rocm") == pf._CUTOVER_FALLBACK
+    # a backend missing from the table is an error, not a default
+    with pytest.raises(ValueError, match="rocm"):
+        pf.topk_loop_cutover("rocm")
 
 
 def test_auto_method_matches_table():
@@ -158,3 +159,28 @@ def test_setup_platform_none_is_flags_only(monkeypatch):
     monkeypatch.delenv("XLA_FLAGS", raising=False)
     pf.setup_platform(None)
     assert "XLA_FLAGS" not in os.environ
+
+
+# --- persistent compile cache ---------------------------------------------
+
+def test_compile_cache_follows_env_else_repo(monkeypatch, tmp_path):
+    import pathlib
+
+    import jax
+
+    # the fixed default: <repo>/.jax_cache, never a run-specific name
+    repo = pathlib.Path(__file__).resolve().parents[1]
+    assert pf.REPO_COMPILE_CACHE == repo / ".jax_cache"
+    calls = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: calls.append((k, v)))
+    # JAX_COMPILATION_CACHE_DIR set: used as is, nothing set in code
+    monkeypatch.setenv(pf.ENV_COMPILE_CACHE, str(tmp_path / "env"))
+    assert pf.enable_compile_cache() == str(tmp_path / "env")
+    assert calls == []
+    # unset: the fixed repo path
+    monkeypatch.delenv(pf.ENV_COMPILE_CACHE)
+    monkeypatch.setattr(pf, "REPO_COMPILE_CACHE", tmp_path / "repo")
+    assert pf.enable_compile_cache() == str(tmp_path / "repo")
+    assert calls == [("jax_compilation_cache_dir", str(tmp_path / "repo"))]
+    assert (tmp_path / "repo").is_dir()

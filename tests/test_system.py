@@ -108,3 +108,41 @@ def test_prefill_logits_match_forward_tail():
         np.asarray(full[:, -1], np.float32), np.asarray(last, np.float32),
         atol=1e-2, rtol=1e-2,
     )
+
+
+def test_f32_step_conserves_against_separate_grad():
+    """With the model in f32 the train step traces under its shard_map
+    and its step 1 from zero memory obeys Mem-SGD's conservation against
+    a separately jitted jax.grad: applied update + new memory ==
+    eta * g (f32 rounding only)."""
+    from repro.core import buckets as bk
+
+    mesh = _mesh11()
+    cfg = get_smoke_config("rwkv6-3b").replace(compute_dtype="float32")
+    model = build_model(cfg)
+    tc = TrainConfig(optimizer="memsgd", eta=0.3,
+                     sync=SyncConfig.preset("topk", ratio=0.01))
+    plan = bk.make_plan(model.param_shapes(), cols=tc.sync.bucket_cols,
+                        dense_below=tc.sync.dense_below)
+    params, memory, opt, count = init_train_state(
+        model, mesh, tc, rng=jax.random.PRNGKey(0))
+    pshard, mshard, _, _ = state_shardings(model, mesh, tc)
+    params = jax.device_put(params, pshard)
+    memory = jax.device_put(memory, mshard)
+    batch = next(iter(ShardedBatcher(
+        mesh, token_batches(cfg.vocab_size, 2, 32, seed=0), prefetch=0)))
+    g = jax.jit(lambda p: bk.pack(
+        plan, jax.grad(lambda q: model.loss(q, batch)[0])(p),
+        dtype=jnp.float32))(params)
+    p0 = jax.device_get(params)
+    p1, m1, _, _, _ = make_train_step(model, mesh, tc)(
+        params, memory, opt, count, batch)
+    applied = bk.pack(plan, jax.tree.map(np.subtract, p0,
+                                         jax.device_get(p1)),
+                      dtype=jnp.float32)
+    r = np.concatenate([np.ravel(a + m[0]) for a, m in zip(applied, m1)])
+    eg = tc.eta * np.concatenate([np.ravel(x) for x in g])
+    assert np.linalg.norm(r - eg) <= 1e-5 * np.linalg.norm(eg)
+    # the selection moved a real share of eta * g into the update
+    assert np.linalg.norm(np.concatenate(
+        [np.ravel(a) for a in applied])) > 0.1 * np.linalg.norm(eg)
