@@ -17,8 +17,11 @@ does the same for the RUNTIME pod-k (k-padded wire) path:
 * **accounting_exact** — the bytes the sync realizes equal the static
   ``bucketed_message_bytes`` prediction, per wire.
 
-Must run under enough host devices for the mesh (see the subprocess
-pattern in tests/test_distributed.py).
+Every probe runs each sync as one ``jax.jit``-compiled ``shard_map``
+program, the form the train step runs in, so the bitwise claims are made
+on compiled code; a runtime-k step takes ``pod_ks`` as an argument and is
+compiled once for all live ks. Must run under enough host devices for
+the mesh (see ``tests/_multidevice.py``).
 """
 from __future__ import annotations
 
@@ -52,11 +55,10 @@ def bitwise_equal(a, b) -> bool:
     )
 
 
-def two_level_selfcheck(mesh, ratio: float = 0.05, pod_ratio: float = 0.1,
-                        eta: float = 0.3) -> dict:
-    """Probe the two-level sync invariants on ``mesh`` (must have axes
-    ``("pod", "data")``). Returns a dict of the three invariant
-    measurements plus the per-wire byte accounting."""
+def _probe_inputs(mesh):
+    """The tiny 2-bucket tree every probe shares (bucket 0 dense, bucket
+    1 sparse at cols=128), with per-worker gradients and memories for
+    the ``(pod, data)`` workers. Returns ``(tree, plan, gs, mem)``."""
     W = int(np.prod([mesh.shape[a] for a in ("pod", "data")]))
     tree = {"w": jax.random.normal(jax.random.PRNGKey(0), (16, 384)),
             "b": jax.random.normal(jax.random.PRNGKey(1), (40,))}
@@ -67,52 +69,93 @@ def two_level_selfcheck(mesh, ratio: float = 0.05, pod_ratio: float = 0.1,
         jax.random.normal(jax.random.PRNGKey(9 + b), (W,) + s.shape)
         * (0.1 if s.kind == "sparse" else 0.0)
         for b, s in enumerate(plan.buckets))
+    return tree, plan, gs, mem
 
-    realized = {}
 
-    def run(wire):
+def compiled_sync(mesh, cfg, plan, eta, *, accumulate=False,
+                  quant_key=None, realized=None, tag=None):
+    """One jitted bucketed sync (any strategy) over the ``(pod, data)``
+    workers: ``step(mem, gs[, pod_ks]) -> (update, new_mem)``, with
+    ``mem``/``gs`` stacked per worker and ``pod_ks`` a replicated
+    runtime argument. ``accumulate`` routes the gradient through the
+    local-steps accumulator (``grad_bufs=``, eta 1). The realized bytes
+    (a static int) land in ``realized[tag]`` when the step is traced."""
+    worker = P(("pod", "data"))
+
+    def sync(m_, g_, *pod_ks):
+        kw = {"pod_ks": pod_ks[0]} if pod_ks else {}
+        if quant_key is not None:
+            kw["quant_key"] = quant_key
+        m0 = jax.tree.map(lambda m: m[0], m_)
+        g0 = jax.tree.map(lambda x: x[0], g_)
+        if accumulate:
+            acc = bk.accumulate_local(plan, bk.init_local_accum(plan), g0,
+                                      jnp.float32(eta))
+            upd, new_mem, nbytes = bucketed_sync_gradients(
+                cfg, plan, m0, None, jnp.float32(1.0), grad_bufs=acc, **kw)
+        else:
+            upd, new_mem, nbytes = bucketed_sync_gradients(
+                cfg, plan, m0, g0, jnp.float32(eta), **kw)
+        if tag is not None:
+            realized[tag] = nbytes
+        return upd, jax.tree.map(lambda m: m[None], new_mem)
+
+    @jax.jit
+    def step(mem, gs, *pod_ks):
+        wspec = jax.tree.map(lambda _: worker, mem)
+        return shard_map(
+            sync, mesh=mesh,
+            in_specs=(wspec, jax.tree.map(lambda _: worker, gs))
+            + (P(),) * len(pod_ks),
+            out_specs=(jax.tree.map(lambda _: P(), gs), wspec),
+        )(mem, gs, *pod_ks)
+
+    return step
+
+
+def _conservation_err(plan, mem, gs, eta, upd, new_mem) -> float:
+    """Two-level mass conservation residual, max over buckets:
+    ``|mean_w(mem + eta * g) - (update + mean_w(new_mem))|``."""
+    W = mem[0].shape[0]
+    g_bufs = [bk.pack(plan, jax.tree.map(lambda x, w=w: x[w], gs),
+                      dtype=jnp.float32) for w in range(W)]
+    upd_bufs = bk.pack(plan, upd, dtype=jnp.float32)
+    err = 0.0
+    for b in range(len(plan.buckets)):
+        u_w = jnp.stack([mem[b][w] + eta * g_bufs[w][b] for w in range(W)])
+        lhs = jnp.mean(u_w, axis=0)
+        rhs = upd_bufs[b] + jnp.mean(new_mem[b], axis=0)
+        err = max(err, float(jnp.max(jnp.abs(lhs - rhs))))
+    return err
+
+
+def _applied(tree, upd):
+    """The params a step leaves behind (``params - update``)."""
+    return jax.tree.map(lambda t, u: t - u, tree, upd)
+
+
+def two_level_selfcheck(mesh, ratio: float = 0.05, pod_ratio: float = 0.1,
+                        eta: float = 0.3) -> dict:
+    """Probe the two-level sync invariants on ``mesh`` (must have axes
+    ``("pod", "data")``). Returns a dict of the three invariant
+    measurements plus the per-wire byte accounting."""
+    _, plan, gs, mem = _probe_inputs(mesh)
+    realized, out, acc = {}, {}, {}
+    for wire in ("packed", "unpacked"):
         cfg = SyncConfig(ratio=ratio, strategy="hierarchical",
                          data_axes=("data",), pod_axis="pod",
                          bucketed=True, bucket_cols=128, wire=wire,
                          pod_ratios=(1.0, pod_ratio))
-
-        def sync(mem, g):
-            upd, new_mem, nbytes = bucketed_sync_gradients(
-                cfg, plan, jax.tree.map(lambda m: m[0], mem),
-                jax.tree.map(lambda x: x[0], g), jnp.float32(eta))
-            realized[wire] = nbytes  # static python int, trace-time
-            return upd, jax.tree.map(lambda m: m[None], new_mem)
-
-        wspec = jax.tree.map(lambda _: P(("pod", "data")), mem)
-        gspec = jax.tree.map(lambda _: P(("pod", "data")), gs)
-        upd, new_mem = shard_map(
-            sync, mesh=mesh, in_specs=(wspec, gspec),
-            out_specs=(jax.tree.map(lambda _: P(), tree), wspec))(mem, gs)
-        return upd, new_mem, cfg
-
-    upd_p, mem_p, cfg_p = run("packed")
-    upd_u, mem_u, cfg_u = run("unpacked")
+        out[wire] = compiled_sync(mesh, cfg, plan, eta, realized=realized,
+                                  tag=wire)(mem, gs)
+        acc[wire] = bucketed_message_bytes(cfg, plan)
     bit = all(np.array_equal(np.asarray(a), np.asarray(b))
-              for a, b in zip(jax.tree.leaves((upd_p, mem_p)),
-                              jax.tree.leaves((upd_u, mem_u))))
-
-    err = 0.0
-    upd_bufs = bk.pack(plan, upd_p, dtype=jnp.float32)
-    for b in range(len(plan.buckets)):
-        u_w = jnp.stack([
-            mem[b][w] + eta * bk.pack(
-                plan, jax.tree.map(lambda x, w=w: x[w], gs),
-                dtype=jnp.float32)[b]
-            for w in range(W)])
-        lhs = jnp.mean(u_w, axis=0)
-        rhs = upd_bufs[b] + jnp.mean(mem_p[b], axis=0)
-        err = max(err, float(jnp.max(jnp.abs(lhs - rhs))))
-
-    acc = {w: bucketed_message_bytes(c, plan)
-           for w, c in (("packed", cfg_p), ("unpacked", cfg_u))}
+              for a, b in zip(jax.tree.leaves(out["packed"]),
+                              jax.tree.leaves(out["unpacked"])))
     return {
         "bit_identical": bool(bit),
-        "conservation_max_err": err,
+        "conservation_max_err": _conservation_err(plan, mem, gs, eta,
+                                                  *out["packed"]),
         "accounting_exact": realized == acc,
         "realized_bytes": realized,
         "accounted_bytes": acc,
@@ -126,14 +169,14 @@ def dynamic_k_selfcheck(mesh, ratio: float = 0.05, eta: float = 0.3,
     ``two_level_selfcheck``. Reports:
 
     * **dynamic_matches_static** — for each wire format and each live k
-      in ``ks``, the dynamic path fed that k as a runtime value is
-      BITWISE identical to the static path compiled at that k, compared
-      on the APPLIED update (params - update) and the new memory:
-      padding the selection to k_max and masking the tail reproduces the
-      static computation. (The raw update may differ in the SIGN of
-      all-zero columns at k_live=1 — XLA's no-reduce special case — a
-      transient ±0.0 that cancels at application; see
-      ``kernels.topk_select.mask_live_k``.)
+      in ``ks``, the dynamic path fed that k as a runtime value (one
+      compiled step for every k) is BITWISE identical to the static path
+      compiled at that k, compared on the APPLIED update (params -
+      update) and the new memory: padding the selection to k_max and
+      masking the tail reproduces the static computation. (The raw
+      update may differ in the SIGN of all-zero columns at k_live=1 —
+      XLA's no-reduce special case — a transient ±0.0 that cancels at
+      application; see ``kernels.topk_select.mask_live_k``.)
     * **conservation_max_err** — two-level mass conservation under a
       SWITCHED live k (the refresh-boundary invariant): for every live
       k, ``mean_w(u) == update + mean_w(new_memory)``.
@@ -143,36 +186,9 @@ def dynamic_k_selfcheck(mesh, ratio: float = 0.05, eta: float = 0.3,
     """
     import dataclasses
 
-    W = int(np.prod([mesh.shape[a] for a in ("pod", "data")]))
+    tree, plan, gs, mem = _probe_inputs(mesh)
     n_data = int(mesh.shape["data"])
-    tree = {"w": jax.random.normal(jax.random.PRNGKey(0), (16, 384)),
-            "b": jax.random.normal(jax.random.PRNGKey(1), (40,))}
-    plan = bk.make_plan(tree, cols=128, dense_below=64)
-    gs = jax.tree.map(lambda x: jnp.stack(
-        [x * (1 + 0.1 * i) + 0.01 * i for i in range(W)]), tree)
-    mem = tuple(
-        jax.random.normal(jax.random.PRNGKey(9 + b), (W,) + s.shape)
-        * (0.1 if s.kind == "sparse" else 0.0)
-        for b, s in enumerate(plan.buckets))
-
     realized = {}
-
-    def run(cfg, pod_ks=None, tag=None):
-        def sync(mem_, g_):
-            kw = {"pod_ks": pod_ks} if pod_ks is not None else {}
-            upd, new_mem, nbytes = bucketed_sync_gradients(
-                cfg, plan, jax.tree.map(lambda m: m[0], mem_),
-                jax.tree.map(lambda x: x[0], g_), jnp.float32(eta), **kw)
-            if tag is not None:
-                realized[tag] = nbytes
-            return upd, jax.tree.map(lambda m: m[None], new_mem)
-
-        wspec = jax.tree.map(lambda _: P(("pod", "data")), mem)
-        gspec = jax.tree.map(lambda _: P(("pod", "data")), gs)
-        return shard_map(
-            sync, mesh=mesh, in_specs=(wspec, gspec),
-            out_specs=(jax.tree.map(lambda _: P(), tree), wspec))(mem, gs)
-
     matches = True
     cons_err = 0.0
     acc_ok = True
@@ -181,31 +197,21 @@ def dynamic_k_selfcheck(mesh, ratio: float = 0.05, eta: float = 0.3,
                          data_axes=("data",), pod_axis="pod",
                          bucketed=True, bucket_cols=128, wire=wire,
                          pod_ratios=(1.0, ks[0] / 128), pod_dynamic=True)
+        dyn_step = compiled_sync(mesh, dyn, plan, eta, realized=realized,
+                                 tag=wire)
         for k_live in ks:
             static = dataclasses.replace(
                 dyn, pod_dynamic=False, pod_ratios=(1.0, k_live / 128))
-            out_s = run(static)
-            tag = f"{wire}@{k_live}"
-            out_d = run(dyn, pod_ks=jnp.asarray([1, k_live], jnp.int32),
-                        tag=tag)
-            applied_s = jax.tree.map(lambda t, u: t - u, tree, out_s[0])
-            applied_d = jax.tree.map(lambda t, u: t - u, tree, out_d[0])
-            matches = matches and bitwise_equal((applied_s, out_s[1]),
-                                                (applied_d, out_d[1]))
-            acc_ok = acc_ok and realized[tag] == bucketed_message_bytes(
+            out_s = compiled_sync(mesh, static, plan, eta)(mem, gs)
+            out_d = dyn_step(mem, gs, jnp.asarray([1, k_live], jnp.int32))
+            matches = matches and bitwise_equal(
+                (_applied(tree, out_s[0]), out_s[1]),
+                (_applied(tree, out_d[0]), out_d[1]))
+            acc_ok = acc_ok and realized[wire] == bucketed_message_bytes(
                 dyn, plan, n_data=n_data)
             # conservation at this live k (the refresh-boundary invariant)
-            upd_bufs = bk.pack(plan, out_d[0], dtype=jnp.float32)
-            for b in range(len(plan.buckets)):
-                u_w = jnp.stack([
-                    mem[b][w] + eta * bk.pack(
-                        plan, jax.tree.map(lambda x, w=w: x[w], gs),
-                        dtype=jnp.float32)[b]
-                    for w in range(W)])
-                lhs = jnp.mean(u_w, axis=0)
-                rhs = upd_bufs[b] + jnp.mean(out_d[1][b], axis=0)
-                cons_err = max(cons_err,
-                               float(jnp.max(jnp.abs(lhs - rhs))))
+            cons_err = max(cons_err,
+                           _conservation_err(plan, mem, gs, eta, *out_d))
     return {
         "dynamic_matches_static": bool(matches),
         "conservation_max_err": cons_err,
@@ -227,31 +233,7 @@ def overlap_selfcheck(mesh, ratio: float = 0.05, eta: float = 0.3,
     tree as ``two_level_selfcheck``."""
     import dataclasses
 
-    W = int(np.prod([mesh.shape[a] for a in ("pod", "data")]))
-    tree = {"w": jax.random.normal(jax.random.PRNGKey(0), (16, 384)),
-            "b": jax.random.normal(jax.random.PRNGKey(1), (40,))}
-    plan = bk.make_plan(tree, cols=128, dense_below=64)
-    gs = jax.tree.map(lambda x: jnp.stack(
-        [x * (1 + 0.1 * i) + 0.01 * i for i in range(W)]), tree)
-    mem0 = tuple(
-        jax.random.normal(jax.random.PRNGKey(9 + b), (W,) + s.shape)
-        * (0.1 if s.kind == "sparse" else 0.0)
-        for b, s in enumerate(plan.buckets))
-
-    def run(cfg, mem_, pod_ks=None):
-        def sync(m_, g_):
-            kw = {"pod_ks": pod_ks} if pod_ks is not None else {}
-            upd, new_mem, _ = bucketed_sync_gradients(
-                cfg, plan, jax.tree.map(lambda m: m[0], m_),
-                jax.tree.map(lambda x: x[0], g_), jnp.float32(eta), **kw)
-            return upd, jax.tree.map(lambda m: m[None], new_mem)
-
-        wspec = jax.tree.map(lambda _: P(("pod", "data")), mem_)
-        gspec = jax.tree.map(lambda _: P(("pod", "data")), gs)
-        return shard_map(
-            sync, mesh=mesh, in_specs=(wspec, gspec),
-            out_specs=(jax.tree.map(lambda _: P(), tree), wspec))(mem_, gs)
-
+    tree, plan, gs, mem0 = _probe_inputs(mesh)
     base = dict(ratio=ratio, data_axes=("data",), pod_axis="pod",
                 bucketed=True, bucket_cols=128, wire=wire)
     paths = {
@@ -264,22 +246,21 @@ def overlap_selfcheck(mesh, ratio: float = 0.05, eta: float = 0.3,
     }
     out = {}
     for name, cfg in paths.items():
+        # pod_dynamic: two chained steps across a live-k REFRESH (9 -> 4)
+        # — the schedule must stay bit-identical through the switch, not
+        # just at a fixed k
+        schedule = ([(jnp.asarray([1, 9], jnp.int32),),
+                     (jnp.asarray([1, 4], jnp.int32),)]
+                    if name == "pod_dynamic" else [()])
         per_overlap = {}
         for ov in (None, False, True):
-            c = dataclasses.replace(cfg, overlap=ov)
+            step = compiled_sync(
+                mesh, dataclasses.replace(cfg, overlap=ov), plan, eta)
             mem_ = mem0
             applied = []
-            # pod_dynamic: two chained steps across a live-k REFRESH
-            # (9 -> 4) — the schedule must stay bit-identical through
-            # the switch, not just at a fixed k
-            schedule = ([[1, 9], [1, 4]] if name == "pod_dynamic"
-                        else [None])
-            for ks in schedule:
-                pk = (None if ks is None
-                      else jnp.asarray(ks, jnp.int32))
-                upd, mem_ = run(c, mem_, pod_ks=pk)
-                applied.append(
-                    jax.tree.map(lambda t, u: t - u, tree, upd))
+            for pod_ks in schedule:
+                upd, mem_ = step(mem_, gs, *pod_ks)
+                applied.append(_applied(tree, upd))
             per_overlap[ov] = (applied, mem_)
         out[f"{name}_bitwise"] = bool(
             bitwise_equal(per_overlap[None], per_overlap[False])
@@ -317,54 +298,23 @@ def local_quant_selfcheck(mesh, ratio: float = 0.05, eta: float = 0.3,
     * **amortized_ratio_exact** — ``amortized_bytes_per_step`` scales
       exactly 1/H for every H in ``hs``.
     """
-    from repro.core.distributed import amortized_bytes_per_step
+    from repro.core.distributed import (
+        PodConfig,
+        WireConfig,
+        amortized_bytes_per_step,
+    )
 
-    W = int(np.prod([mesh.shape[a] for a in ("pod", "data")]))
-    tree = {"w": jax.random.normal(jax.random.PRNGKey(0), (16, 384)),
-            "b": jax.random.normal(jax.random.PRNGKey(1), (40,))}
-    plan = bk.make_plan(tree, cols=128, dense_below=64)
-    gs = jax.tree.map(lambda x: jnp.stack(
-        [x * (1 + 0.1 * i) + 0.01 * i for i in range(W)]), tree)
-    mem0 = tuple(
-        jax.random.normal(jax.random.PRNGKey(9 + b), (W,) + s.shape)
-        * (0.1 if s.kind == "sparse" else 0.0)
-        for b, s in enumerate(plan.buckets))
+    _, plan, gs, mem0 = _probe_inputs(mesh)
     realized = {}
 
-    def run(cfg, accumulate=False, pod_ks=None, tag=None):
-        qk = (jax.random.PRNGKey(5) if cfg.quant is not None else None)
-
-        def sync(m_, g_):
-            kw = {}
-            if pod_ks is not None:
-                kw["pod_ks"] = pod_ks
-            if qk is not None:
-                kw["quant_key"] = qk
-            g0 = jax.tree.map(lambda x: x[0], g_)
-            if accumulate:
-                acc = bk.init_local_accum(plan)
-                acc = bk.accumulate_local(plan, acc, g0, jnp.float32(eta))
-                kw["grad_bufs"] = acc
-                upd, new_mem, nbytes = bucketed_sync_gradients(
-                    cfg, plan, jax.tree.map(lambda m: m[0], m_), None,
-                    jnp.float32(1.0), **kw)
-            else:
-                upd, new_mem, nbytes = bucketed_sync_gradients(
-                    cfg, plan, jax.tree.map(lambda m: m[0], m_), g0,
-                    jnp.float32(eta), **kw)
-            if tag is not None:
-                realized[tag] = nbytes
-            return upd, jax.tree.map(lambda m: m[None], new_mem)
-
-        wspec = jax.tree.map(lambda _: P(("pod", "data")), mem0)
-        gspec = jax.tree.map(lambda _: P(("pod", "data")), gs)
-        return shard_map(
-            sync, mesh=mesh, in_specs=(wspec, gspec),
-            out_specs=(jax.tree.map(lambda _: P(), tree), wspec))(mem0, gs)
+    def run(cfg, *pod_ks, accumulate=False, tag=None):
+        qk = jax.random.PRNGKey(5) if cfg.quant is not None else None
+        return compiled_sync(mesh, cfg, plan, eta, accumulate=accumulate,
+                             quant_key=qk, realized=realized,
+                             tag=tag)(mem0, gs, *pod_ks)
 
     base = dict(ratio=ratio, data_axes=("data",), bucketed=True,
                 bucket_cols=128)
-    from repro.core.distributed import PodConfig, WireConfig
 
     # 1) H=1 accumulator routing is bitwise-invisible on every strategy
     paths = {
@@ -382,10 +332,10 @@ def local_quant_selfcheck(mesh, ratio: float = 0.05, eta: float = 0.3,
     }
     h1_ok = True
     for name, cfg in paths.items():
-        pk = (jnp.asarray([1, 9], jnp.int32) if name == "pod_dynamic"
-              else None)
-        direct = run(cfg, accumulate=False, pod_ks=pk)
-        accum = run(cfg, accumulate=True, pod_ks=pk)
+        pk = ((jnp.asarray([1, 9], jnp.int32),) if name == "pod_dynamic"
+              else ())
+        direct = run(cfg, *pk)
+        accum = run(cfg, *pk, accumulate=True)
         h1_ok = h1_ok and bitwise_equal(direct, accum)
 
     # 2) quantized tier: conservation + packed/unpacked identity + bytes
@@ -406,16 +356,8 @@ def local_quant_selfcheck(mesh, ratio: float = 0.05, eta: float = 0.3,
         bit_ok = bit_ok and bitwise_equal(out_p, out_u)
         acc_ok = acc_ok and realized[f"{name}-packed"] == (
             bucketed_message_bytes(mk("packed"), plan))
-        upd_bufs = bk.pack(plan, out_p[0], dtype=jnp.float32)
-        for b in range(len(plan.buckets)):
-            u_w = jnp.stack([
-                mem0[b][w] + eta * bk.pack(
-                    plan, jax.tree.map(lambda x, w=w: x[w], gs),
-                    dtype=jnp.float32)[b]
-                for w in range(W)])
-            lhs = jnp.mean(u_w, axis=0)
-            rhs = upd_bufs[b] + jnp.mean(out_p[1][b], axis=0)
-            cons_err = max(cons_err, float(jnp.max(jnp.abs(lhs - rhs))))
+        cons_err = max(cons_err,
+                       _conservation_err(plan, mem0, gs, eta, *out_p))
 
     # 3) amortized byte accounting scales exactly 1/H
     q = SyncConfig(strategy="sparse_allgather", pod=PodConfig(axis="pod"),
@@ -469,32 +411,8 @@ def repack_selfcheck(mesh, ratio: float = 0.05, eta: float = 0.3,
     from repro.core.distributed import repack_transport
     from repro.kernels.topk_select import mask_live_k
 
-    W = int(np.prod([mesh.shape[a] for a in ("pod", "data")]))
+    tree, plan, gs, mem0 = _probe_inputs(mesh)
     n_data = int(mesh.shape["data"])
-    tree = {"w": jax.random.normal(jax.random.PRNGKey(0), (16, 384)),
-            "b": jax.random.normal(jax.random.PRNGKey(1), (40,))}
-    plan = bk.make_plan(tree, cols=128, dense_below=64)
-    gs = jax.tree.map(lambda x: jnp.stack(
-        [x * (1 + 0.1 * i) + 0.01 * i for i in range(W)]), tree)
-    mem0 = tuple(
-        jax.random.normal(jax.random.PRNGKey(9 + b), (W,) + s.shape)
-        * (0.1 if s.kind == "sparse" else 0.0)
-        for b, s in enumerate(plan.buckets))
-
-    def run(cfg, mem_, pod_ks):
-        def sync(m_, g_):
-            upd, new_mem, _ = bucketed_sync_gradients(
-                cfg, plan, jax.tree.map(lambda m: m[0], m_),
-                jax.tree.map(lambda x: x[0], g_), jnp.float32(eta),
-                pod_ks=pod_ks)
-            return upd, jax.tree.map(lambda m: m[None], new_mem)
-
-        wspec = jax.tree.map(lambda _: P(("pod", "data")), mem_)
-        gspec = jax.tree.map(lambda _: P(("pod", "data")), gs)
-        return shard_map(
-            sync, mesh=mesh, in_specs=(wspec, gspec),
-            out_specs=(jax.tree.map(lambda _: P(), tree), wspec))(mem_, gs)
-
     dyn = SyncConfig(ratio=ratio, strategy="hierarchical",
                      data_axes=("data",), pod_axis="pod", bucketed=True,
                      bucket_cols=128, wire="packed",
@@ -502,12 +420,14 @@ def repack_selfcheck(mesh, ratio: float = 0.05, eta: float = 0.3,
     outs = {}
     for rp in (False, True):
         for ov in (None, False, True):
-            c = dataclasses.replace(dyn, repack=rp, overlap=ov)
+            step = compiled_sync(
+                mesh, dataclasses.replace(dyn, repack=rp, overlap=ov),
+                plan, eta)
             mem_, applied = mem0, []
             for k_live in ks:  # chained steps across the live-k switch
-                upd, mem_ = run(c, mem_,
-                                jnp.asarray([1, k_live], jnp.int32))
-                applied.append(jax.tree.map(lambda t, u: t - u, tree, upd))
+                upd, mem_ = step(mem_, gs,
+                                 jnp.asarray([1, k_live], jnp.int32))
+                applied.append(_applied(tree, upd))
             outs[(rp, ov)] = (applied, mem_)
     ref = outs[(False, None)]
     repack_bitwise = all(bitwise_equal(ref, v) for v in outs.values())

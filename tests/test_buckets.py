@@ -1,18 +1,13 @@
 """Bucketed flat-buffer engine tests (repro.core.buckets)."""
-import os
-import subprocess
-import sys
-
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
+
+from _multidevice import probe_fixture
 
 from repro.core import buckets as bk
 from repro.core import compression as C
 from repro.core.memsgd import constant_eta, memsgd_bucketed
-
-SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 
 def _tree(key=0):
@@ -134,13 +129,13 @@ def test_bucketed_sync_single_worker_matches_memory_step():
         )
         return upd, new_mem
 
-    upd, new_mem = shard_map(
+    upd, new_mem = jax.jit(shard_map(
         body, mesh=mesh,
         in_specs=(jax.tree.map(lambda _: jax.sharding.PartitionSpec(), mem),
                   jax.tree.map(lambda _: jax.sharding.PartitionSpec(), tree)),
         out_specs=(jax.tree.map(lambda _: jax.sharding.PartitionSpec(), tree),
                    jax.tree.map(lambda _: jax.sharding.PartitionSpec(), mem)),
-    )(mem, tree)
+    ))(mem, tree)
 
     k_for = lambda c: cfg.k_for(c)
     applied, want_mem, _ = bk.bucket_memory_step(
@@ -156,22 +151,8 @@ def test_bucketed_sync_single_worker_matches_memory_step():
         )
 
 
-@pytest.mark.slow
-def test_distributed_bucketed_memsgd_loss_decreases():
-    """Full train step with sync.bucketed on a 4-worker mesh (model=1)."""
-    import json
-    import textwrap
-
-    script = textwrap.dedent(
-        """
-        import os
-        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-        import sys, json
-        sys.path.insert(0, {src!r})
-        """
-    ).format(src=SRC) + textwrap.dedent(
-        """
-        import jax, jax.numpy as jnp
+probes = probe_fixture({
+    "bucketed_train": """
         from repro.configs import get_smoke_config
         from repro.models import build_model
         from repro.launch.mesh import make_debug_mesh
@@ -201,15 +182,14 @@ def test_distributed_bucketed_memsgd_loss_decreases():
             params, memory, opt, count, m = step(params, memory, opt,
                                                  count, batch)
             losses.append(float(m["loss"]))
-        print(json.dumps({"first": losses[0], "last": losses[-1],
-                          "n_buckets": len(memory)}))
-        """
-    )
-    out = subprocess.run(
-        [sys.executable, "-c", script], capture_output=True, text=True,
-        timeout=560,
-    )
-    assert out.returncode == 0, out.stderr[-3000:]
-    rec = json.loads(out.stdout.strip().splitlines()[-1])
+        rec = {"first": losses[0], "last": losses[-1],
+               "n_buckets": len(memory)}
+        """,
+}, timeout=120)
+
+
+def test_distributed_bucketed_memsgd_loss_decreases(probes):
+    """Full train step with sync.bucketed on a 4-worker mesh (model=1)."""
+    rec = probes("bucketed_train")
     assert rec["last"] < rec["first"]
     assert rec["n_buckets"] <= 4

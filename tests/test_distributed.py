@@ -1,40 +1,14 @@
 """Distributed PARALLEL-MEM-SGD tests.
 
-Multi-device cases run in a SUBPROCESS with
-``XLA_FLAGS=--xla_force_host_platform_device_count=8`` so the main pytest
-process keeps the single real CPU device (per the dry-run contract).
+Multi-device cases run in the file's one child process with
+``XLA_FLAGS=--xla_force_host_platform_device_count=8`` (tests/_multidevice.py)
+so the main pytest process keeps the single real CPU device (per the
+dry-run contract).
 """
-import json
-import os
-import subprocess
-import sys
-import textwrap
-
-import jax
 import jax.numpy as jnp
-import numpy as np
 import pytest
 
-SRC = os.path.join(os.path.dirname(__file__), "..", "src")
-
-def _run_subprocess(body: str) -> dict:
-    """Run `body` with 8 fake devices; it must print one JSON line."""
-    script = textwrap.dedent(
-        """
-        import os
-        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-        import sys, json
-        sys.path.insert(0, {src!r})
-        import jax, jax.numpy as jnp
-        import numpy as np
-        """
-    ).format(src=SRC) + textwrap.dedent(body)
-    out = subprocess.run(
-        [sys.executable, "-c", script], capture_output=True, text=True,
-        timeout=560,
-    )
-    assert out.returncode == 0, out.stderr[-3000:]
-    return json.loads(out.stdout.strip().splitlines()[-1])
+from _multidevice import child_watchdog, probe_fixture
 
 
 def test_message_bytes_accounting():
@@ -71,10 +45,13 @@ def test_sync_col_axes_rules():
     assert specs["blocks"]["mlp"]["w_down"] == P(None, "model", None)
 
 
-@pytest.mark.slow
-def test_distributed_memsgd_loss_decreases():
-    rec = _run_subprocess(
-        """
+# The child's limit is the largest of any file: "hier_vs_flat" compiles a
+# hierarchical step whose pod stage unrolls one argmax pass per kept
+# column (pod_ratio 1.0 keeps the full row), minutes of XLA compile on a
+# CPU.
+CHILD_TIMEOUT = 600
+probes = probe_fixture({
+    "memsgd_loss": """
         from repro.configs import get_smoke_config
         from repro.models import build_model
         from repro.launch.mesh import make_debug_mesh
@@ -103,20 +80,9 @@ def test_distributed_memsgd_loss_decreases():
             params, memory, opt, count, m = step(params, memory, opt, count,
                                                  batch)
             losses.append(float(m["loss"]))
-        print(json.dumps({"first": losses[0], "last": losses[-1]}))
-        """
-    )
-    assert rec["last"] < rec["first"]
-
-
-@pytest.mark.slow
-def test_distributed_sparse_sync_no_dense_allreduce():
-    """The compiled train step must NOT contain a dense gradient
-    all-reduce: the biggest all-reduce operand must be far smaller than
-    the largest parameter (the paper's communication claim, verified on
-    the compiled HLO)."""
-    rec = _run_subprocess(
-        """
+        rec = {"first": losses[0], "last": losses[-1]}
+        """,
+    "no_dense_allreduce": """
         from repro.configs import get_smoke_config
         from repro.models import build_model
         from repro.launch.mesh import make_debug_mesh
@@ -157,21 +123,9 @@ def test_distributed_sparse_sync_no_dense_allreduce():
                 biggest_ar = max(biggest_ar, parse_hlo_shape_bytes(m.group(1)))
         biggest_param = max(
             int(np.prod(l.shape)) * 4 for l in jax.tree.leaves(params))
-        print(json.dumps({"biggest_ar": biggest_ar,
-                          "biggest_param": biggest_param}))
-        """
-    )
-    # dense sync would all-reduce the largest param (>= MBs); the sparse
-    # scheme's all-reduces are only scalar metrics / norm reductions.
-    assert rec["biggest_ar"] < rec["biggest_param"] / 50
-
-
-@pytest.mark.slow
-def test_hierarchical_matches_flat_when_pod_ratio_full():
-    """With pod re-compression disabled (pod_ratio=1.0 => k_pod = full
-    row), hierarchical == flat sparse_allgather updates after one step."""
-    rec = _run_subprocess(
-        """
+        rec = {"biggest_ar": biggest_ar, "biggest_param": biggest_param}
+        """,
+    "hier_vs_flat": """
         from repro.configs import get_smoke_config
         from repro.models import build_model
         from repro.launch.train import (TrainConfig, make_train_step,
@@ -205,7 +159,35 @@ def test_hierarchical_matches_flat_when_pod_ratio_full():
         diff = max(float(jnp.max(jnp.abs(a - b)))
                    for a, b in zip(jax.tree.leaves(p_flat),
                                    jax.tree.leaves(p_hier)))
-        print(json.dumps({"maxdiff": diff}))
-        """
-    )
+        rec = {"maxdiff": diff}
+        """,
+}, timeout=CHILD_TIMEOUT)
+
+
+@pytest.mark.slow
+@child_watchdog(CHILD_TIMEOUT)
+def test_distributed_memsgd_loss_decreases(probes):
+    rec = probes("memsgd_loss")
+    assert rec["last"] < rec["first"]
+
+
+@pytest.mark.slow
+@child_watchdog(CHILD_TIMEOUT)
+def test_distributed_sparse_sync_no_dense_allreduce(probes):
+    """The compiled train step must NOT contain a dense gradient
+    all-reduce: the biggest all-reduce operand must be far smaller than
+    the largest parameter (the paper's communication claim, verified on
+    the compiled HLO)."""
+    rec = probes("no_dense_allreduce")
+    # dense sync would all-reduce the largest param (>= MBs); the sparse
+    # scheme's all-reduces are only scalar metrics / norm reductions.
+    assert rec["biggest_ar"] < rec["biggest_param"] / 50
+
+
+@pytest.mark.slow
+@child_watchdog(CHILD_TIMEOUT)
+def test_hierarchical_matches_flat_when_pod_ratio_full(probes):
+    """With pod re-compression disabled (pod_ratio=1.0 => k_pod = full
+    row), hierarchical == flat sparse_allgather updates after one step."""
+    rec = probes("hier_vs_flat")
     assert rec["maxdiff"] < 1e-5

@@ -5,14 +5,9 @@ repro.checkpoint.Checkpointer.save_wire).
 Fast tests drive the hub with a SYNTHETIC sparse update stream: per-step
 bucket updates with support <= the delta spec's k' bound, so the packed
 encode captures them exactly — the same contract the trainer guarantees
-(see repro.launch.delta_stream). The slow subprocess test replays the
-real trainer end to end on 4 fake devices."""
-import json
-import os
-import subprocess
-import sys
+(see repro.launch.delta_stream). The slow test replays the real trainer
+end to end on 4 fake devices, in the file's one child."""
 import tempfile
-import textwrap
 
 import jax
 import jax.numpy as jnp
@@ -20,6 +15,7 @@ import numpy as np
 import pytest
 
 from _hypothesis_compat import given, settings, st
+from _multidevice import probe_fixture
 
 from repro.checkpoint import Checkpointer
 from repro.core import buckets as bk
@@ -27,8 +23,6 @@ from repro.core import encoding as enc
 from repro.core.distributed import SyncConfig, _row_scatter, _row_topk
 from repro.launch import delta_stream as ds
 from repro.launch.fanout import FanoutHub
-
-SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 
 # -- synthetic trainer-side stream -------------------------------------------
@@ -345,37 +339,11 @@ def test_replica_copy_survives_trainer_donation():
     assert not _bitwise(params, replica)
 
 
-# -- end-to-end with the real trainer (subprocess, 4 fake devices) -----------
+# -- end-to-end with the real trainer (the file's one child, 4 devices) ------
 
 
-def _run_subprocess(body: str) -> dict:
-    script = textwrap.dedent(
-        """
-        import os
-        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-        import sys, json
-        sys.path.insert(0, {src!r})
-        import jax, jax.numpy as jnp
-        import numpy as np
-        """
-    ).format(src=SRC) + textwrap.dedent(body)
-    out = subprocess.run(
-        [sys.executable, "-c", script], capture_output=True, text=True,
-        timeout=560,
-    )
-    assert out.returncode == 0, out.stderr[-3000:]
-    return json.loads(out.stdout.strip().splitlines()[-1])
-
-
-@pytest.mark.slow
-def test_fanout_replicas_track_real_trainer():
-    """Acceptance: replicas subscribed at different steps — one steady,
-    one joining mid-stream inside the log, one joining past the replay
-    bound (forcing a wire-compressed snapshot resync) — all end
-    bitwise-equal to the real Mem-SGD trainer on the f32 tier, while a
-    bf16-tier replica stays within the hub's drift bound."""
-    rec = _run_subprocess(
-        """
+probes = probe_fixture({
+    "fanout": """
         from repro.configs import get_smoke_config
         from repro.models import build_model
         from repro.launch.mesh import make_debug_mesh
@@ -438,7 +406,7 @@ def test_fanout_replicas_track_real_trainer():
             for a, b in zip(jax.tree.leaves(params),
                             jax.tree.leaves(lossy.params)))
         snap_step, snap_recs, snap_bytes = hub.snapshot()
-        print(json.dumps({
+        rec = {
             "steady": bitwise(params, steady.params),
             "mid": bitwise(params, mid.params),
             "late": bitwise(params, late.params),
@@ -449,9 +417,19 @@ def test_fanout_replicas_track_real_trainer():
             "snap_bytes": snap_bytes,
             "snap_dense": sum(r.dense_nbytes for r in snap_recs),
             "stats": hub.stats(),
-        }))
-        """
-    )
+        }
+        """,
+}, devices=4, timeout=240)
+
+
+@pytest.mark.slow
+def test_fanout_replicas_track_real_trainer(probes):
+    """Acceptance: replicas subscribed at different steps — one steady,
+    one joining mid-stream inside the log, one joining past the replay
+    bound (forcing a wire-compressed snapshot resync) — all end
+    bitwise-equal to the real Mem-SGD trainer on the f32 tier, while a
+    bf16-tier replica stays within the hub's drift bound."""
+    rec = probes("fanout")
     assert rec["steady"] and rec["mid"] and rec["late"], rec
     assert rec["late_resyncs"] >= 1 and rec["mid_resyncs"] >= 1
     assert rec["drift_ok"] and rec["drift_under_bound"], rec

@@ -1,18 +1,12 @@
 """Two-level pod-aware bucketed sync tests (repro.core.distributed).
 
-Fast tier: per-bucket pod-k resolution, per-level byte accounting, and
-the mass-capture autotuner — pure accounting, no devices. Slow tier:
-the property the scheme lives or dies by, checked on a REAL 8-device
-2-pod mesh in a subprocess (pattern from tests/test_distributed.py):
-exact mass conservation across BOTH residual levels and packed ==
-unpacked bit-identity.
+Per-bucket pod-k resolution, per-level byte accounting, and the
+mass-capture autotuner — pure accounting, no devices. Then the property
+the scheme lives or dies by, checked on a REAL 8-device 2-pod mesh in
+the file's one child (tests/_multidevice.py): exact mass conservation
+across BOTH residual levels and packed == unpacked bit-identity.
 """
 import dataclasses
-import json
-import os
-import subprocess
-import sys
-import textwrap
 
 import jax
 import jax.numpy as jnp
@@ -26,9 +20,8 @@ from repro.core.distributed import (
     bucketed_message_bytes,
 )
 
+from _multidevice import probe_fixture
 from tests._hypothesis_compat import given, settings, st
-
-SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 
 def _tree(key=0, heavy=1.0):
@@ -180,57 +173,34 @@ def test_mass_capture_monotone_and_complete():
     )
 
 
-_SUBPROCESS_CACHE: dict = {}
+_TWO_LEVEL_CASES = [(0.05, 0.1), (0.02, 0.05), (0.05, 1.0)]
 
-
-@pytest.mark.slow
-@settings(max_examples=5, deadline=None)
-@given(case=st.sampled_from([(0.05, 0.1), (0.02, 0.05), (0.05, 1.0)]))
-def test_two_level_conservation_and_wire_bit_identity(case):
-    """On a real 2-pod x 4-worker mesh (shared probe:
-    ``repro.core.selfcheck.two_level_selfcheck``): (1) the two-level
-    mass-conservation invariant mean_w(u) == update + mean_w(new_memory)
-    holds exactly (both residual levels fold back into bucket memory),
-    (2) packed and unpacked wires produce BITWISE identical updates and
-    memories, (3) the bytes the sync realizes equal the static
-    ``bucketed_message_bytes`` accounting. Each (ratio, pod_ratio) case
-    costs two shard_map compiles in a fresh subprocess, so results are
-    memoized across the sweep's repeated draws."""
-    ratio, pod_ratio = case
-    body = """
+probes = probe_fixture({
+    f"two_level_{ratio}_{pod_ratio}": f"""
         from repro.core.selfcheck import two_level_selfcheck
         from repro.utils.compat import make_mesh
 
         rec = two_level_selfcheck(
             make_mesh((2, 4), ("pod", "data")),
             ratio={ratio}, pod_ratio={pod_ratio})
-        print(json.dumps(rec))
         """
-    if case not in _SUBPROCESS_CACHE:
-        _SUBPROCESS_CACHE[case] = _run_subprocess(
-            body.format(ratio=ratio, pod_ratio=pod_ratio)
-        )
-    rec = _SUBPROCESS_CACHE[case]
+    for ratio, pod_ratio in _TWO_LEVEL_CASES
+}, timeout=100)
+
+
+@pytest.mark.parametrize("case", _TWO_LEVEL_CASES, ids=str)
+def test_two_level_conservation_and_wire_bit_identity(probes, case):
+    """On a real 2-pod x 4-worker mesh (shared probe:
+    ``repro.core.selfcheck.two_level_selfcheck``): (1) the two-level
+    mass-conservation invariant mean_w(u) == update + mean_w(new_memory)
+    holds exactly (both residual levels fold back into bucket memory),
+    (2) packed and unpacked wires produce BITWISE identical updates and
+    memories, (3) the bytes the sync realizes equal the static
+    ``bucketed_message_bytes`` accounting. All three (ratio, pod_ratio)
+    cases run in the file's one child."""
+    ratio, pod_ratio = case
+    rec = probes(f"two_level_{ratio}_{pod_ratio}")
     assert rec["bit_identical"]
     assert rec["conservation_max_err"] < 1e-5, rec
     assert rec["accounting_exact"], rec
     assert rec["accounted_bytes"]["packed"] < rec["accounted_bytes"]["unpacked"]
-
-
-def _run_subprocess(body: str) -> dict:
-    script = textwrap.dedent(
-        """
-        import os
-        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-        import sys, json
-        sys.path.insert(0, {src!r})
-        import jax, jax.numpy as jnp
-        import numpy as np
-        """
-    ).format(src=SRC) + textwrap.dedent(body)
-    out = subprocess.run(
-        [sys.executable, "-c", script], capture_output=True, text=True,
-        timeout=560,
-    )
-    assert out.returncode == 0, out.stderr[-3000:]
-    return json.loads(out.stdout.strip().splitlines()[-1])

@@ -1,17 +1,13 @@
 """Qsparse-local-SGD tests: composed contraction theory, the
-amortized byte accounting, the H local-steps accumulator, and (slow
-tier) the 8-device selfcheck of the H=1 bitwise identity + quantized
+amortized byte accounting, the H local-steps accumulator, and the
+8-device selfcheck of the H=1 bitwise identity + quantized
 mass conservation (``repro.core.selfcheck.local_quant_selfcheck``)."""
-import json
-import os
-import subprocess
-import sys
-import textwrap
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+
+from _multidevice import probe_fixture
 
 from repro.core import buckets as bk
 from repro.core import theory
@@ -21,8 +17,6 @@ from repro.core.distributed import (
     amortized_bytes_per_step,
     bucketed_message_bytes,
 )
-
-SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 
 # -- theory: the composed Q_s ∘ top_k contraction ---------------------------
@@ -149,30 +143,20 @@ def test_make_train_step_rejects_local_steps_without_buckets():
         make_train_step(model, mesh, tc)
 
 
-# -- slow tier: 8-device selfcheck ------------------------------------------
+# -- 8-device selfcheck (the file's one child) -----------------------------
 
 
-def _run_subprocess(body: str) -> dict:
-    script = textwrap.dedent(
-        """
-        import os
-        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-        import sys, json
-        sys.path.insert(0, {src!r})
-        import jax, jax.numpy as jnp
-        import numpy as np
-        """
-    ).format(src=SRC) + textwrap.dedent(body)
-    out = subprocess.run(
-        [sys.executable, "-c", script], capture_output=True, text=True,
-        timeout=560,
-    )
-    assert out.returncode == 0, out.stderr[-3000:]
-    return json.loads(out.stdout.strip().splitlines()[-1])
+probes = probe_fixture({
+    "local_quant": """
+        from repro.core.selfcheck import local_quant_selfcheck
+        from repro.utils.compat import make_mesh
+
+        rec = local_quant_selfcheck(make_mesh((2, 4), ("pod", "data")))
+        """,
+}, timeout=100)
 
 
-@pytest.mark.slow
-def test_local_quant_selfcheck():
+def test_local_quant_selfcheck(probes):
     """On a real 2-pod x 4-worker mesh: (1) the H=1 accumulator path
     (init_local_accum + accumulate_local + sync(grad_bufs, eta=1)) is
     BITWISE identical to the direct per-step sync on all three
@@ -180,15 +164,7 @@ def test_local_quant_selfcheck():
     mean_w(new_mem) holds exactly, (3) packed and unpacked quantized
     wires agree bitwise, (4) realized bytes == the quant-aware
     accounting, (5) amortized bytes scale exactly 1/H."""
-    rec = _run_subprocess(
-        """
-        from repro.core.selfcheck import local_quant_selfcheck
-        from repro.utils.compat import make_mesh
-
-        rec = local_quant_selfcheck(make_mesh((2, 4), ("pod", "data")))
-        print(json.dumps(rec))
-        """
-    )
+    rec = probes("local_quant")
     assert rec["h1_accum_bitwise"], rec
     assert rec["quant_conservation_max_err"] < 1e-5, rec
     assert rec["quant_bit_identical"], rec

@@ -10,11 +10,6 @@ False, True} is BITWISE identical on applied params + memory across all
 three sync paths on a real 8-device 2-pod mesh, including a mid-run
 pod-k refresh (``repro.core.selfcheck.overlap_selfcheck``).
 """
-import json
-import os
-import subprocess
-import sys
-import textwrap
 import time
 
 import jax
@@ -34,9 +29,8 @@ from repro.core.pipeline import (
     validate_schedule,
 )
 
+from _multidevice import child_watchdog, probe_fixture
 from tests._hypothesis_compat import given, settings, st
-
-SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 E, G, R = COMPUTE, COMM, REPACK
 FLAT = (E, G, E)          # select+encode / gather / decode+apply
@@ -247,26 +241,38 @@ def test_run_schedule_none_depth_needs_no_kinds_order():
     assert np.array_equal(np.asarray(out[0][1]), np.asarray(st0[1]))
 
 
-_SUBPROCESS_CACHE: dict = {}
+_OVERLAP = """
+    from repro.core.selfcheck import overlap_selfcheck
+    from repro.utils.compat import make_mesh
+
+    rec = overlap_selfcheck(make_mesh((2, 4), ("pod", "data")),
+                            wire={wire!r})
+    """
+
+# About three times the child's 117-143 s inside a whole run: the two
+# overlap probes compile 9 syncs each (3 paths x 3 overlap modes).
+CHILD_TIMEOUT = 420
+probes = probe_fixture({
+    "overlap_packed": _OVERLAP.format(wire="packed"),
+    "overlap_unpacked": _OVERLAP.format(wire="unpacked"),
+    "repack": """
+        from repro.core.selfcheck import repack_selfcheck
+        from repro.utils.compat import make_mesh
+
+        rec = repack_selfcheck(make_mesh((2, 4), ("pod", "data")))
+        """,
+}, timeout=CHILD_TIMEOUT)
 
 
 @pytest.mark.slow
-def test_overlap_bitwise_identity_all_paths():
+@child_watchdog(CHILD_TIMEOUT)
+def test_overlap_bitwise_identity_all_paths(probes):
     """flat / hierarchical / pod-dynamic (with a mid-run live-k switch)
     on a real 2-pod x 4-worker mesh: overlap in {None, False, True}
     applies BITWISE identical params and memory
-    (``repro.core.selfcheck.overlap_selfcheck``)."""
-    key = "overlap_selfcheck"
-    body = """
-        from repro.core.selfcheck import overlap_selfcheck
-        from repro.utils.compat import make_mesh
-
-        rec = overlap_selfcheck(make_mesh((2, 4), ("pod", "data")))
-        print(json.dumps(rec))
-        """
-    if key not in _SUBPROCESS_CACHE:
-        _SUBPROCESS_CACHE[key] = _run_subprocess(body)
-    rec = _SUBPROCESS_CACHE[key]
+    (``repro.core.selfcheck.overlap_selfcheck``, packed wire, its
+    default)."""
+    rec = probes("overlap_packed")
     assert rec["flat_bitwise"], rec
     assert rec["hierarchical_bitwise"], rec
     assert rec["pod_dynamic_bitwise"], rec
@@ -274,63 +280,26 @@ def test_overlap_bitwise_identity_all_paths():
 
 
 @pytest.mark.slow
-@settings(max_examples=3, deadline=None)
-@given(wire=st.sampled_from(["unpacked", "packed"]))
-def test_overlap_bitwise_identity_per_wire(wire):
+@child_watchdog(CHILD_TIMEOUT)
+@pytest.mark.parametrize("wire", ["unpacked", "packed"])
+def test_overlap_bitwise_identity_per_wire(probes, wire):
     """The identity holds for both wire formats (the packed encode/
     decode split across pipeline stages is the riskier path)."""
-    body = """
-        from repro.core.selfcheck import overlap_selfcheck
-        from repro.utils.compat import make_mesh
-
-        rec = overlap_selfcheck(make_mesh((2, 4), ("pod", "data")),
-                                wire={wire!r})
-        print(json.dumps(rec))
-        """
-    if wire not in _SUBPROCESS_CACHE:
-        _SUBPROCESS_CACHE[wire] = _run_subprocess(body.format(wire=wire))
-    assert _SUBPROCESS_CACHE[wire]["bitwise_all"], _SUBPROCESS_CACHE[wire]
+    rec = probes(f"overlap_{wire}")
+    assert rec["bitwise_all"], rec
 
 
 @pytest.mark.slow
-def test_repack_bitwise_identity_and_transport():
+@child_watchdog(CHILD_TIMEOUT)
+def test_repack_bitwise_identity_and_transport(probes):
     """``SyncConfig.repack`` on a real 2-pod mesh: the in-jit R stage is
     bitwise inert across overlap modes and a live-k switch, the host
     ``repack_transport`` round-trips the padded buffer bitwise (inline
     and over an ``EmulatedLink``), and its realized bytes equal the
     live-k accounting exactly (``repro.core.selfcheck.repack_selfcheck``)."""
-    key = "repack_selfcheck"
-    body = """
-        from repro.core.selfcheck import repack_selfcheck
-        from repro.utils.compat import make_mesh
-
-        rec = repack_selfcheck(make_mesh((2, 4), ("pod", "data")))
-        print(json.dumps(rec))
-        """
-    if key not in _SUBPROCESS_CACHE:
-        _SUBPROCESS_CACHE[key] = _run_subprocess(body)
-    rec = _SUBPROCESS_CACHE[key]
+    rec = probes("repack")
     assert rec["repack_bitwise"], rec
     assert rec["transport_roundtrip_bitwise"], rec
     assert rec["transport_accounting_exact"], rec
     padded, live = rec["padded_vs_live_bytes"]
     assert live < padded, rec
-
-
-def _run_subprocess(body: str) -> dict:
-    script = textwrap.dedent(
-        """
-        import os
-        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-        import sys, json
-        sys.path.insert(0, {src!r})
-        import jax, jax.numpy as jnp
-        import numpy as np
-        """
-    ).format(src=SRC) + textwrap.dedent(body)
-    out = subprocess.run(
-        [sys.executable, "-c", script], capture_output=True, text=True,
-        timeout=560,
-    )
-    assert out.returncode == 0, out.stderr[-3000:]
-    return json.loads(out.stdout.strip().splitlines()[-1])

@@ -1,23 +1,20 @@
 """Runtime pod-k (k-padded wire) + live-refresh tests.
 
-Fast tier: the masking/accounting machinery (no devices, or a tiny
+The masking/accounting machinery (no devices, or a tiny
 in-process (1, 1) pod mesh), the live-k wire header, the autotune k
 caps, and the delta-spec k_max support bound (the upward-refresh
-regression). Slow tier: the dynamic==static / conservation / accounting
-probe on a REAL 8-device 2-pod mesh
+regression). The dynamic==static / conservation / accounting probe on
+a REAL 8-device 2-pod mesh runs in the file's one child
 (``repro.core.selfcheck.dynamic_k_selfcheck``).
 """
 import dataclasses
-import json
-import os
-import subprocess
-import sys
-import textwrap
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+
+from _multidevice import probe_fixture
 
 from repro.core import buckets as bk
 from repro.core import encoding as enc
@@ -30,8 +27,6 @@ from repro.core.distributed import (
 from repro.core.selfcheck import bitwise_equal
 from repro.kernels.topk_select import mask_live_k
 from repro.launch import delta_stream as ds
-
-SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 
 # -- mask_live_k: padded static selection == smaller static selection --------
@@ -168,10 +163,10 @@ def test_dynamic_pod_k_matches_static_single_device():
     compared on the APPLIED update (params - update) and the memory, the
     state that actually persists (the raw update's all-zero columns may
     differ in zero SIGN at k_live=1: XLA's no-reduce special case; see
-    ``mask_live_k``) — for several live ks through one computation."""
-    from jax.sharding import PartitionSpec as P
-
-    from repro.utils.compat import make_mesh, shard_map
+    ``mask_live_k``) — for several live ks through one compiled step
+    that takes the live k as an argument."""
+    from repro.core.selfcheck import compiled_sync
+    from repro.utils.compat import make_mesh
 
     if jax.device_count() < 1:
         pytest.skip("no devices")
@@ -182,30 +177,18 @@ def test_dynamic_pod_k_matches_static_single_device():
                 for s in plan.buckets)
     gs = jax.tree.map(lambda x: x[None], tree)
 
-    def run(cfg, pod_ks=None):
-        def sync(mem_, g_):
-            kw = {"pod_ks": pod_ks} if pod_ks is not None else {}
-            upd, new_mem, _ = bucketed_sync_gradients(
-                cfg, plan, jax.tree.map(lambda m: m[0], mem_),
-                jax.tree.map(lambda x: x[0], g_), jnp.float32(0.4), **kw)
-            return upd, jax.tree.map(lambda m: m[None], new_mem)
-
-        spec = jax.tree.map(lambda _: P(("pod", "data")), mem)
-        gspec = jax.tree.map(lambda _: P(("pod", "data")), gs)
-        return shard_map(
-            sync, mesh=mesh, in_specs=(spec, gspec),
-            out_specs=(jax.tree.map(lambda _: P(), tree), spec))(mem, gs)
-
     for wire in ("unpacked", "packed"):
         dyn = SyncConfig(ratio=0.1, strategy="hierarchical",
                          data_axes=("data",), pod_axis="pod",
                          bucketed=True, bucket_cols=64, wire=wire,
                          pod_ratios=(0.05,), pod_dynamic=True)
+        dyn_step = compiled_sync(mesh, dyn, plan, 0.4)  # one program, any k
         for k_live in (1, 3, 6):
             static = dataclasses.replace(
                 dyn, pod_dynamic=False, pod_ratios=(k_live / 64,))
-            upd_s, mem_s = run(static)
-            upd_d, mem_d = run(dyn, pod_ks=jnp.asarray([k_live], jnp.int32))
+            upd_s, mem_s = compiled_sync(mesh, static, plan, 0.4)(mem, gs)
+            upd_d, mem_d = dyn_step(mem, gs,
+                                    jnp.asarray([k_live], jnp.int32))
             applied_s = jax.tree.map(lambda t, u: t - u, tree, upd_s)
             applied_d = jax.tree.map(lambda t, u: t - u, tree, upd_d)
             assert bitwise_equal((applied_s, mem_s), (applied_d, mem_d)), \
@@ -277,34 +260,25 @@ def test_delta_spec_survives_upward_k_refresh():
     assert not np.array_equal(np.asarray(old_rec[1]), buf)
 
 
-# -- slow: real 2-pod mesh probe ---------------------------------------------
+# -- real 2-pod mesh probe (the file's one child) ---------------------------
 
 
-@pytest.mark.slow
-def test_dynamic_k_selfcheck_on_2pod_mesh():
-    """dynamic==static bitwise, conservation under a switched live k,
-    and padded accounting, on a REAL 8-device 2-pod mesh (shared probe:
-    ``repro.core.selfcheck.dynamic_k_selfcheck`` — the same harness the
-    refresh bench runs)."""
-    script = textwrap.dedent(
-        """
-        import os
-        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-        import sys, json
-        sys.path.insert(0, {src!r})
+probes = probe_fixture({
+    "dynamic_k": """
         from repro.core.selfcheck import dynamic_k_selfcheck
         from repro.utils.compat import make_mesh
 
         rec = dynamic_k_selfcheck(make_mesh((2, 4), ("pod", "data")))
-        print(json.dumps(rec))
-        """
-    ).format(src=SRC)
-    out = subprocess.run(
-        [sys.executable, "-c", script], capture_output=True, text=True,
-        timeout=560,
-    )
-    assert out.returncode == 0, out.stderr[-3000:]
-    rec = json.loads(out.stdout.strip().splitlines()[-1])
+        """,
+}, timeout=90)
+
+
+def test_dynamic_k_selfcheck_on_2pod_mesh(probes):
+    """dynamic==static bitwise, conservation under a switched live k,
+    and padded accounting, on a REAL 8-device 2-pod mesh (shared probe:
+    ``repro.core.selfcheck.dynamic_k_selfcheck`` — the same harness the
+    refresh bench runs)."""
+    rec = probes("dynamic_k")
     assert rec["dynamic_matches_static"], rec
     assert rec["conservation_max_err"] < 1e-5, rec
     assert rec["accounting_exact"], rec

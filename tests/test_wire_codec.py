@@ -1,26 +1,19 @@
 """Packed sparse wire codec + delta stream tests (repro.core.encoding,
 repro.launch.delta_stream).
 
-Multi-worker cases run in a subprocess with 8 fake CPU devices (same
-contract as test_distributed.py)."""
-import json
-import os
-import subprocess
-import sys
-import textwrap
-
+Multi-worker cases run in the file's one child with 8 fake CPU devices
+(tests/_multidevice.py)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from _hypothesis_compat import given, settings, st
+from _multidevice import probe_fixture
 
 from repro.core import buckets as bk
 from repro.core import encoding as enc
 from repro.core.distributed import SyncConfig, bucketed_message_bytes
-
-SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 
 def _pairs(rows, cols, k, value_dtype, seed=0):
@@ -269,32 +262,8 @@ def test_message_bytes_packed_smaller_than_unpacked():
 # -- packed sync == unpacked sync, end to end ---------------------------------
 
 
-def _run_subprocess(body: str) -> dict:
-    script = textwrap.dedent(
-        """
-        import os
-        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-        import sys, json
-        sys.path.insert(0, {src!r})
-        import jax, jax.numpy as jnp
-        import numpy as np
-        """
-    ).format(src=SRC) + textwrap.dedent(body)
-    out = subprocess.run(
-        [sys.executable, "-c", script], capture_output=True, text=True,
-        timeout=560,
-    )
-    assert out.returncode == 0, out.stderr[-3000:]
-    return json.loads(out.stdout.strip().splitlines()[-1])
-
-
-@pytest.mark.slow
-def test_packed_sync_identical_to_unpacked():
-    """Packed-wire sync is bit-identical to the unpacked path on an
-    8-worker mesh: bucketed for both value dtypes, plus the leaf-wise
-    path (flat + hierarchical share the same leaf sync functions)."""
-    rec = _run_subprocess(
-        """
+probes = probe_fixture({
+    "packed_vs_unpacked": """
         import dataclasses
         from repro.core import buckets as bk
         from repro.core.distributed import (SyncConfig,
@@ -324,12 +293,12 @@ def test_packed_sync_identical_to_unpacked():
                     cfg, plan, mem, tree, jnp.float32(0.3))
                 return upd, jax.tree.map(lambda m: m[None], new_mem)
             spec_w = jax.tree.map(lambda _: P(axes), mem)
-            return shard_map(
+            return jax.jit(shard_map(
                 body, mesh=mesh,
                 in_specs=(spec_w, jax.tree.map(lambda _: P(axes), tree)),
                 out_specs=(jax.tree.map(lambda _: P(), {k: 0 for k in tree}),
                            spec_w),
-                axis_names=set(mesh.axis_names))(mem, tree)
+                axis_names=set(mesh.axis_names)))(mem, tree)
 
         results = {}
         flat_mesh = make_mesh((8,), ("data",))
@@ -357,29 +326,20 @@ def test_packed_sync_identical_to_unpacked():
                 tree = jax.tree.map(lambda t: t[0], tree)
                 return sparse_sync_gradients(
                     cfg, mem0, tree, jnp.float32(0.3))[:2]
-            return shard_map(
+            return jax.jit(shard_map(
                 body, mesh=flat_mesh,
                 in_specs=(jax.tree.map(lambda _: P("data"), tree),),
                 out_specs=(jax.tree.map(lambda _: P(), tree),) * 2,
-                axis_names={"data"})(tree)
+                axis_names={"data"}))(tree)
 
         leaf_cfg = SyncConfig(ratio=0.02, dense_below=256)
         u1, m1 = run_leaf(leaf_cfg)
         u2, m2 = run_leaf(dataclasses.replace(leaf_cfg, wire="packed"))
         results["leafwise_float32"] = bool(bitwise_equal(u1, u2)
                                            and bitwise_equal(m1, m2))
-        print(json.dumps(results))
-        """
-    )
-    assert all(rec.values()), rec
-
-
-@pytest.mark.slow
-def test_delta_stream_replica_tracks_trainer_bitwise():
-    """3 trainer steps with emit_deltas; streaming the packed deltas to a
-    fresh replica reproduces the trainer's params bitwise."""
-    rec = _run_subprocess(
-        """
+        rec = results
+        """,
+    "delta_stream": """
         from repro.configs import get_smoke_config
         from repro.models import build_model
         from repro.launch.mesh import make_debug_mesh
@@ -416,10 +376,27 @@ def test_delta_stream_replica_tracks_trainer_bitwise():
             assert sum(b.size * 4 for b in delta) == dspec.nbytes
             streamed += dspec.nbytes
             replica = apply_delta(replica, dspec, delta)
-        print(json.dumps({"bitwise": bool(bitwise_equal(params, replica)),
-                          "streamed": streamed,
-                          "dense": dspec.dense_nbytes * 3}))
-        """
-    )
+        rec = {"bitwise": bool(bitwise_equal(params, replica)),
+               "streamed": streamed,
+               "dense": dspec.dense_nbytes * 3}
+        """,
+}, timeout=180)
+
+
+@pytest.mark.slow
+def test_packed_sync_identical_to_unpacked(probes):
+    """Packed-wire sync is bit-identical to the unpacked path on an
+    8-worker mesh, each run compiled: bucketed for both value dtypes,
+    plus the leaf-wise path (flat + hierarchical share the same leaf
+    sync functions)."""
+    rec = probes("packed_vs_unpacked")
+    assert all(rec.values()), rec
+
+
+@pytest.mark.slow
+def test_delta_stream_replica_tracks_trainer_bitwise(probes):
+    """3 trainer steps with emit_deltas; streaming the packed deltas to a
+    fresh replica reproduces the trainer's params bitwise."""
+    rec = probes("delta_stream")
     assert rec["bitwise"]
     assert rec["streamed"] * 4 < rec["dense"]
