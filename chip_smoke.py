@@ -4,7 +4,7 @@ widths on a TPU, plus every Pallas kernel compiled for the chip.
 
     python chip_smoke.py             # one chip: train + kernel phases
     python chip_smoke.py --chips 4   # (4, 1) data mesh: topk vs dense only
-    python chip_smoke.py --kernel-sweep  # one chip, plus loop/threshold times
+    python chip_smoke.py --kernel-sweep  # one chip: fused kernel times only
 
 One chip (default):
 
@@ -23,6 +23,12 @@ One chip (default):
 ``--chips 4`` trains the same config on a (4, 1) data mesh at global
 batch 32 with the ``topk`` preset and then the ``dense`` preset on the
 same batches, and checks where the state lives.
+
+``--kernel-sweep`` (one chip) times the fused Mem-SGD kernel alone at
+the sparse bucket of granite-3-8b (published widths, 2 layers: the
+benchmark's configuration), over k, with the loop select against the
+threshold select: the data for the TPU entry of
+``repro.utils.platform.TOPK_LOOP_CUTOVER``. Nothing is trained.
 
 The last stdout line is one JSON object naming the device. The script
 exits non-zero, with no such line, when JAX finds no TPU, when
@@ -50,8 +56,8 @@ RATIO = 0.01  # k = 10 of every 1024-column bucket row
 ETA = 0.3
 KERNEL_ETA = 0.37
 TIMED_CALLS = 5  # timed kernel calls after the first
-SWEEP_ROWS = 65536  # --kernel-sweep: rows of the bucket it times
-SWEEP_KS = (10, 32, 64, 128)
+SWEEP_ARCH = "granite-3-8b"  # --kernel-sweep: whose bucket it times
+SWEEP_KS = (10, 32, 64)
 # Step-1 conservation, gated twice (see _gradient_gate, _conserved_step1).
 # 1. Against a separately jitted jax.grad: the same make_train_step with
 #    the model in f32 at HIGHEST matmul precision, on the first
@@ -456,7 +462,7 @@ def phase_train_one_chip(jax) -> dict:
     return out
 
 
-def phase_kernels(jax, inputs: dict, sweep: bool = False) -> None:
+def phase_kernels(jax, inputs: dict) -> None:
     import jax.numpy as jnp
 
     from repro.kernels import ops, ref
@@ -507,8 +513,6 @@ def phase_kernels(jax, inputs: dict, sweep: bool = False) -> None:
             ops.fused_memsgd_update, {"k": k, "method": method},
             (m, g, eta), want)
     log(f"[kernels] peak_bytes_in_use {_peak_bytes(jax)}")
-    if sweep:
-        _cutover_sweep(jax, m[:SWEEP_ROWS], bitwise)
 
 
 def _timed(jax, compiled, args):
@@ -526,20 +530,53 @@ def _timed(jax, compiled, args):
     return out, first, float(np.median(ts))
 
 
-def _cutover_sweep(jax, x, bitwise) -> None:
-    """Loop against threshold selection (``ops.row_topk``) over k, on the
-    first SWEEP_ROWS rows of the bucket: the data for the TPU entry of
-    ``repro.utils.platform.TOPK_LOOP_CUTOVER``. Both must agree bitwise."""
-    from repro.kernels import ops
+def phase_kernel_sweep(jax) -> None:
+    """The fused kernel (``ops.fused_memsgd_update``) at SWEEP_ARCH's
+    sparse bucket, loop against threshold select, for each k of
+    SWEEP_KS; both must agree bitwise."""
+    import jax.numpy as jnp
 
+    from repro.configs import sized_config
+    from repro.core import buckets as bk
+    from repro.kernels import ops
+    from repro.models import build_model
+
+    cfg = sized_config(SWEEP_ARCH, SIZE, LAYERS).replace(tie_embeddings=True)
+    plan = bk.make_plan(build_model(cfg).param_shapes(), cols=1024)
+    (spec,) = [s for s in plan.buckets if s.kind == "sparse"]
+    key = jax.random.PRNGKey(0)
+    m = jax.random.normal(key, spec.shape, jnp.float32) * 1e-3
+    # g flat, as the train step packs it (core.buckets.pack)
+    g = jax.random.normal(jax.random.fold_in(key, 1),
+                          (spec.rows * spec.cols,), jnp.float32)
+    eta = jnp.float32(KERNEL_ETA)
+
+    @jax.jit
+    def same_bits(a, b):  # one fused reduction: no bucket-sized copy
+        if a.dtype == jnp.float32:
+            a = jax.lax.bitcast_convert_type(a, jnp.int32)
+            b = jax.lax.bitcast_convert_type(b, jnp.int32)
+        return jnp.array_equal(a, b)
+
+    # the bucket's bytes through HBM once (read m and g, write m'), as
+    # the floor every fused kernel is read against
+    floor = jax.jit(lambda m, g, e: m + e * g.reshape(m.shape)).lower(
+        m, g, eta).compile()
+    _, _, floor_s = _timed(jax, floor, (m, g, eta))
+    log(f"[sweep] {SWEEP_ARCH} bucket {spec.shape}: m + eta*g alone "
+        f"{floor_s:.6f} s (median of {TIMED_CALLS})")
     for k in SWEEP_KS:
         got, sec = {}, {}
         for method in ("loop", "threshold"):
-            compiled = ops.row_topk.lower(x, k=k, method=method).compile()
-            got[method], _, sec[method] = _timed(jax, compiled, (x,))
-        same = all(bitwise(a, b) for a, b in zip(got["loop"],
-                                                  got["threshold"]))
-        log(f"[sweep] {x.shape[0]} x {x.shape[1]} k {k}: loop "
+            compiled = jax.jit(lambda m, g, e, method=method: (
+                ops.fused_memsgd_update(m, g.reshape(m.shape), e, k,
+                                        method=method))).lower(
+                m, g, eta).compile()
+            got[method], _, sec[method] = _timed(jax, compiled, (m, g, eta))
+        same = all(bool(same_bits(a, b)) for a, b in zip(got["loop"],
+                                                          got["threshold"]))
+        del got
+        log(f"[sweep] {spec.shape[0]} x {spec.shape[1]} k {k}: loop "
             f"{sec['loop']:.6f} s threshold {sec['threshold']:.6f} s "
             f"(median of {TIMED_CALLS}); loop/threshold "
             f"{sec['loop'] / sec['threshold']:.3f}; bitwise equal {same}")
@@ -610,8 +647,9 @@ def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--chips", type=int, default=1, choices=(1, 4))
     ap.add_argument("--kernel-sweep", action="store_true",
-                    help="one chip: also time loop against threshold "
-                    "top-k over k at the bucket width")
+                    help="one chip: time only the fused Mem-SGD kernel, "
+                    "loop against threshold select over k, at "
+                    f"{SWEEP_ARCH}'s bucket")
     args = ap.parse_args()
     _refuse_environment()
     sys.path.insert(0, SRC)
@@ -625,9 +663,10 @@ def main() -> None:
         f"(platform {device['platform']}); compile cache {cache}")
     if args.chips == 4:
         phase_four_chips(jax)
+    elif args.kernel_sweep:
+        phase_kernel_sweep(jax)
     else:
-        inputs = phase_train_one_chip(jax)
-        phase_kernels(jax, inputs, sweep=args.kernel_sweep)
+        phase_kernels(jax, phase_train_one_chip(jax))
     print(json.dumps({"ok": True, "device": device}), flush=True)
 
 

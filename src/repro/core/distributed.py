@@ -17,6 +17,10 @@ This is the distributed heart of the framework. It runs INSIDE a
      by W,
   6. keeps m_w' = u_w - own_selection.
 
+On a TPU the bucketed flat stage does 2, 3 and 6 in one Pallas kernel
+(``repro.kernels.fused_memsgd``): one pass over m_w and g_w that writes
+m_w' in place and emits the pairs (``_fused_engages`` says where).
+
 Row-block top-k (TPU adaptation of the paper's top_k)
 -----------------------------------------------------
 A global top-k over a tensor-parallel parameter would require gathering
@@ -49,11 +53,13 @@ Sync strategies
 from __future__ import annotations
 
 import dataclasses
+import functools
 import warnings
 from typing import Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.core import pipeline
 from repro.core.pipeline import COMM, COMPUTE, QUANT, REPACK
@@ -796,10 +802,39 @@ def _encode_quant(wspec, codes, idx, norms, live_n=None):
     )
 
 
+def _fused_engages(cfg: "SyncConfig", k_row: int) -> bool:
+    """Whether a flat sparse bucket runs the fused Pallas Mem-SGD kernel
+    (``repro.kernels.ops.fused_memsgd_update``: one HBM pass for
+    ``u = m + eta*g``, the per-row top-k and the residual) in place of
+    the XLA select, own densify and ``u - own``. Only where Mosaic
+    compiles it (a TPU), with no quantised wire (the memory must then
+    absorb ``u - dequantised own``, which a zeroed residual does not)
+    and at k up to the loop select's cutover; everywhere else the XLA
+    path runs. Both give the same selection and memory."""
+    from repro.utils import platform
+
+    return (platform.backend() == "tpu" and cfg.quant is None
+            and k_row <= platform.topk_loop_cutover())
+
+
+def _all_axes_manual(fn):
+    """``fn`` with every axis of the current mesh manual. The step's
+    shard_map leaves the model axis to GSPMD, which cannot partition a
+    Mosaic kernel; a bucket is replicated over that axis, so each of its
+    devices runs ``fn`` on the whole bucket."""
+    mesh = jax.sharding.get_abstract_mesh()
+    auto = set(mesh.axis_names) - set(mesh.manual_axes)
+    if not auto:
+        return fn
+    return jax.shard_map(fn, mesh=mesh, in_specs=P(), out_specs=P(),
+                         axis_names=auto, check_vma=False)
+
+
 def _sparse_stages(shape, dtype, k_row, axes, value_dtype,
                    constrain=lambda x: x, topk=_row_topk,
                    densify=None, wire: str = "unpacked",
-                   quant: Optional[int] = None, qkey=None):
+                   quant: Optional[int] = None, qkey=None,
+                   fused_eta=None):
     """Stage chain for one flat sparse leaf/bucket, decomposed for the
     bucket pipeline (repro.core.pipeline):
 
@@ -814,7 +849,11 @@ def _sparse_stages(shape, dtype, k_row, axes, value_dtype,
     Returns ``(stages, kinds, nbytes)``; stage 0 takes ``u`` (..., C)
     and the final stage returns ``(mean update, own selection)``. Run
     back to back, the no-quant stages compute EXACTLY the op sequence
-    the old monolithic ``_leaf_sparse_sync`` emitted."""
+    the old monolithic ``_leaf_sparse_sync`` emitted.
+
+    With ``fused_eta`` (no quant; see ``_fused_engages``) stage E is the
+    fused kernel instead: it takes ``(m, g)`` of a (rows, C) bucket and
+    the final stage returns ``(mean update, new memory)``."""
     densify = densify or _row_scatter
     rows = 1
     for s in shape[:-1]:
@@ -831,14 +870,22 @@ def _sparse_stages(shape, dtype, k_row, axes, value_dtype,
         wspec = None
         nbytes = rows * k_row * (jnp.dtype(value_dtype).itemsize + 4)
 
+    def encode(vals, idx):
+        if wspec is not None:
+            return _encode_packed(vals.astype(value_dtype), idx, wspec)
+        return vals.astype(value_dtype), idx
+
     def select_encode(u):
         vals, idx = topk(u, k_row, constrain)
-        own = densify(shape, vals, idx, dtype, constrain)
-        if wspec is not None:
-            payload = _encode_packed(vals.astype(value_dtype), idx, wspec)
-        else:
-            payload = (vals.astype(value_dtype), idx)
-        return own, payload
+        return densify(shape, vals, idx, dtype, constrain), encode(vals, idx)
+
+    def fused_update_encode(mg):
+        from repro.kernels.ops import fused_memsgd_update
+
+        kernel = _all_axes_manual(functools.partial(
+            fused_memsgd_update, k=k_row, method="loop"))
+        new_m, vals, idx = kernel(*mg, fused_eta)
+        return new_m, encode(vals, idx)
 
     def select(u):
         return topk(u, k_row, constrain)
@@ -877,8 +924,8 @@ def _sparse_stages(shape, dtype, k_row, axes, value_dtype,
                 "quantized sparse stages need a qkey (threaded PRNG key)")
         return ([select, quantize_encode, gather, decode_apply],
                 (COMPUTE, QUANT, COMM, COMPUTE), nbytes)
-    return ([select_encode, gather, decode_apply],
-            (COMPUTE, COMM, COMPUTE), nbytes)
+    first = select_encode if fused_eta is None else fused_update_encode
+    return ([first, gather, decode_apply], (COMPUTE, COMM, COMPUTE), nbytes)
 
 
 def _leaf_sparse_sync(u: Array, k_row: int, axes, value_dtype,
@@ -1309,9 +1356,21 @@ def bucketed_sync_gradients(
     all_axes = tuple(cfg.data_axes) + (
         (cfg.pod_axis,) if cfg.pod_axis else ()
     )
+    flat = cfg.strategy != "dense" and not (
+        cfg.strategy == "hierarchical" and cfg.pod_axis is not None)
+    fused = [flat and spec.kind != "dense"
+             and _fused_engages(cfg, cfg.k_for(spec.cols))
+             for spec in plan.buckets]
     if grad_bufs is not None:
         g_bufs = [b.astype(jnp.float32) for b in grad_bufs]
     else:
+        if any(fused):
+            # the whole gradient before the pack: left to interleave the
+            # pack with the backward pass, XLA holds 0.86 GB more
+            # temporaries around the kernel in granite-3-8b's step (6.41
+            # against 5.55 GB compiled for a v5e; the XLA path does
+            # better without, 6.10 against 6.50)
+            grad_tree = jax.lax.optimization_barrier(grad_tree)
         g_bufs = bk.pack(plan, grad_tree, dtype=jnp.float32)
     # Build every bucket's stage chain up front, then emit in the
     # planned (possibly double-buffered) order. The finish closures run
@@ -1320,7 +1379,7 @@ def bucketed_sync_gradients(
     inits, stage_lists, kind_lists, finishes = [], [], [], []
     total_bytes = 0
     for b, (spec, m, g) in enumerate(zip(plan.buckets, memory_bufs, g_bufs)):
-        u = m + eta * g
+        u = init = m + eta * g
         bkey = (jax.random.fold_in(quant_key, b)
                 if quant_key is not None else None)
         if cfg.strategy == "dense" or spec.kind == "dense":
@@ -1361,19 +1420,28 @@ def bucketed_sync_gradients(
                 return upd, (u - own) + residual
         elif cfg.strategy in ("sparse_allgather", "hierarchical"):
             k_row = cfg.k_for(spec.cols)
-            topk, densify = _pick_selection(cfg, k_row)
-            stages, kinds, nbytes = _sparse_stages(
-                u.shape, u.dtype, k_row, all_axes, value_dtype,
-                topk=topk, densify=densify, wire=cfg.wire,
-                quant=cfg.quant, qkey=bkey,
-            )
+            if fused[b]:
+                # one kernel pass: stage 0 takes (m, g), and the chain
+                # ends with (update, new memory)
+                stages, kinds, nbytes = _sparse_stages(
+                    m.shape, m.dtype, k_row, all_axes, value_dtype,
+                    wire=cfg.wire, fused_eta=eta)
+                init = (m, g)
+                finish = lambda st: st
+            else:
+                topk, densify = _pick_selection(cfg, k_row)
+                stages, kinds, nbytes = _sparse_stages(
+                    u.shape, u.dtype, k_row, all_axes, value_dtype,
+                    topk=topk, densify=densify, wire=cfg.wire,
+                    quant=cfg.quant, qkey=bkey,
+                )
 
-            def finish(st, u=u):
-                upd, own = st
-                return upd, u - own
+                def finish(st, u=u):
+                    upd, own = st
+                    return upd, u - own
         else:
             raise ValueError(f"unknown sync strategy {cfg.strategy!r}")
-        inits.append(u)
+        inits.append(init)
         stage_lists.append(stages)
         kind_lists.append(kinds)
         finishes.append(finish)

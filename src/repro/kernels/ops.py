@@ -27,7 +27,10 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ref
-from repro.kernels.fused_memsgd import fused_memsgd_pallas
+from repro.kernels.fused_memsgd import (
+    fused_memsgd_pallas,
+    fused_memsgd_threshold_pallas,
+)
 from repro.kernels.topk_select import (
     DEFAULT_COL_BLOCK,
     DEFAULT_ROW_BLOCK,
@@ -79,23 +82,23 @@ def row_topk(x: Array, k: int, row_block: int = DEFAULT_ROW_BLOCK,
     return vals, idx
 
 
-@functools.partial(
-    jax.jit, static_argnames=("k", "row_block", "interpret", "method")
-)
+@functools.partial(jax.jit, static_argnames=("k", "interpret", "method"))
 def fused_memsgd_update(
-    m: Array, g: Array, eta, k: int, row_block: int = DEFAULT_ROW_BLOCK,
-    interpret: Optional[bool] = None, method: str = "auto",
+    m: Array, g: Array, eta, k: int, interpret: Optional[bool] = None,
+    method: str = "auto",
 ) -> Tuple[Array, Array, Array]:
     """Fused u = m + eta*g -> top-k -> residual memory.
 
-    Returns (new_m (R,C), vals (R,k), idx (R,k)).
+    Returns (new_m (R,C), vals (R,k), idx (R,k)). The loop select writes
+    new_m over m (donate m to keep one bucket-sized buffer) and needs no
+    row padding; the threshold select pads the rows to its row block.
     """
-    mp, pad = _pad_rows(m, row_block)
-    gp, _ = _pad_rows(g, row_block)
-    new_m, vals, idx = fused_memsgd_pallas(
-        mp, gp, eta, k, row_block=row_block, interpret=interpret,
-        selection=_resolve_method(method, k),
-    )
+    if _resolve_method(method, k) == "loop":
+        return fused_memsgd_pallas(m, g, eta, k, interpret=interpret)
+    mp, pad = _pad_rows(m, DEFAULT_ROW_BLOCK)
+    gp, _ = _pad_rows(g, DEFAULT_ROW_BLOCK)
+    new_m, vals, idx = fused_memsgd_threshold_pallas(
+        mp, gp, eta, k, interpret=interpret)
     if pad:
         new_m = new_m[: m.shape[0]]
         vals, idx = vals[: m.shape[0]], idx[: m.shape[0]]
